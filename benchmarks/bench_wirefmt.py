@@ -27,7 +27,8 @@ throughput is the headline.  Checks:
 arena path uploads each batch as ONE contiguous uint8 slab and runs the
 Pallas fused crop/mirror/normalize on device; the materialize path is the
 classic CPU pipeline (per-sample frombuffer -> f32 -> crop/mirror ->
-normalize -> transpose -> upload).  Checks:
+normalize -> transpose -> upload).  The kernel runs in the Pallas
+interpreter: this section measures host CPU, not the device.  Checks:
 
 * ``arena_matches_materialize`` — both paths produce identical tensors
   (same seeded augmentation draws);
@@ -202,7 +203,7 @@ def _pixel_feed(store, uuids, ds, use_arena: bool, batch_size: int,
                        arena_slot_bytes=ds.nbytes, seed=SEED)
     loader = CassandraLoader(store, uuids, cfg)
     feed = ImageFeed(loader, ds.h, ds.w, ds.c, out_h=out_hw, out_w=out_hw,
-                     seed=SEED + 1)
+                     seed=SEED + 1, interpret=True)
     return loader, feed
 
 
@@ -227,7 +228,7 @@ def run_arena_section(quick: bool) -> dict:
     zero = jnp.zeros((batch_size,), jnp.int32)
     kernel_ops.crop_mirror_normalize(
         warm, zero, zero, zero, jnp.zeros(3), jnp.ones(3),
-        out_h=out_hw, out_w=out_hw).block_until_ready()
+        out_h=out_hw, out_w=out_hw, interpret=True).block_until_ready()
     jax.device_put(np.zeros((batch_size, 3, out_hw, out_hw),
                             np.float32)).block_until_ready()
 

@@ -85,7 +85,8 @@ def _build_feed(kind: str, loader: CassandraLoader, *,
                 seq_len: Optional[int],
                 image_shape: Optional[Tuple[int, int, int]],
                 out_shape: Optional[Tuple[int, int]],
-                feed_prefetch: int, step_stats, mean, std, feed_seed: int):
+                feed_prefetch: int, step_stats, mean, std, feed_seed: int,
+                interpret: bool):
     from repro.data.pipeline import DeviceFeed, ImageFeed
     if kind == "device":
         if seq_len is None:
@@ -101,7 +102,7 @@ def _build_feed(kind: str, loader: CassandraLoader, *,
     out_h, out_w = out_shape
     return ImageFeed(loader, h, w, c, out_h, out_w, mean=mean, std=std,
                      seed=feed_seed, prefetch=feed_prefetch,
-                     step_stats=step_stats)
+                     step_stats=step_stats, interpret=interpret)
 
 
 def build_stack(*, store: KVStore, uuids: Sequence[_uuid.UUID],
@@ -116,7 +117,8 @@ def build_stack(*, store: KVStore, uuids: Sequence[_uuid.UUID],
                 out_shape: Optional[Tuple[int, int]] = None,
                 feed_prefetch: int = 2,
                 step_stats=None,
-                mean=None, std=None, feed_seed: int = 0) -> Stack:
+                mean=None, std=None, feed_seed: int = 0,
+                interpret: bool = False) -> Stack:
     """Assemble the full data stack from one config object.
 
     Parameters
@@ -138,8 +140,9 @@ def build_stack(*, store: KVStore, uuids: Sequence[_uuid.UUID],
         ``None`` (default), ``"device"`` (token batches; needs ``seq_len``
         and ``config.materialize=True``) or ``"image"`` (uint8 image rows;
         needs ``image_shape``/``out_shape`` and ``materialize=True``).
-    feed_prefetch, step_stats, mean, std, feed_seed
-        Passed through to the feed constructor.
+    feed_prefetch, step_stats, mean, std, feed_seed, interpret
+        Passed through to the feed constructor (``interpret=True`` runs the
+        image feed's Pallas kernel in the interpreter, for CPU callers).
     """
     if feed not in FEED_KINDS:
         raise ValueError(f"unknown feed kind {feed!r} "
@@ -175,7 +178,7 @@ def build_stack(*, store: KVStore, uuids: Sequence[_uuid.UUID],
                                image_shape=image_shape, out_shape=out_shape,
                                feed_prefetch=feed_prefetch,
                                step_stats=step_stats, mean=mean, std=std,
-                               feed_seed=feed_seed)
+                               feed_seed=feed_seed, interpret=interpret)
     if start:
         loader.start()
     return Stack(config=config, clock=loader.clock, cluster=loader.cluster,

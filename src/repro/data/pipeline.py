@@ -133,6 +133,17 @@ class DeviceFeed:
         return dev_batch, meta
 
 
+def augment_draws(rng: np.random.Generator, B: int, h: int, w: int,
+                  out_h: int, out_w: int):
+    """One batch's crop offsets and mirror flags, ``(oy, ox, mirror)`` int32
+    of shape ``(B,)``.  ``ImageFeed`` makes one such draw per batch in pull
+    order; a checker that replays the same seed gets the same draws."""
+    oy = rng.integers(0, h - out_h + 1, size=B)
+    ox = rng.integers(0, w - out_w + 1, size=B)
+    mirror = rng.integers(0, 2, size=B)
+    return oy.astype(np.int32), ox.astype(np.int32), mirror.astype(np.int32)
+
+
 class ImageFeed:
     """Loader -> device feed for fixed-size pixel rows (e.g.
     ``SyntheticPixelDataset``) with fused on-device crop/mirror/normalize.
@@ -162,14 +173,18 @@ class ImageFeed:
     def __init__(self, loader: CassandraLoader, h: int, w: int, c: int,
                  out_h: int, out_w: int,
                  mean=None, std=None, seed: int = 0, prefetch: int = 2,
-                 step_stats: Optional[StepStats] = None) -> None:
+                 step_stats: Optional[StepStats] = None,
+                 interpret: bool = False) -> None:
         self.loader = loader
         self.h, self.w, self.c = h, w, c
         self.out_h, self.out_w = out_h, out_w
         self.mean = np.asarray(
             mean if mean is not None else [127.5] * c, dtype=np.float32)
-        self.std = np.asarray(
+        # DALI's form: one host reciprocal, then (x - mean) * inv_std on
+        # both paths, so the kernel and the NumPy transform agree bit for bit
+        self.inv_std = np.float32(1.0) / np.asarray(
             std if std is not None else [64.0] * c, dtype=np.float32)
+        self.interpret = interpret
         self.prefetch = prefetch
         self.step_stats = step_stats or StepStats(loader.clock)
         self.mode = "arena" if getattr(loader, "arena", None) else "materialize"
@@ -179,13 +194,6 @@ class ImageFeed:
         self._queue: collections.deque = collections.deque()
         self._started = False
 
-    def _augment_draws(self, B: int):
-        oy = self._rng.integers(0, self.h - self.out_h + 1, size=B)
-        ox = self._rng.integers(0, self.w - self.out_w + 1, size=B)
-        mirror = self._rng.integers(0, 2, size=B)
-        return (oy.astype(np.int32), ox.astype(np.int32),
-                mirror.astype(np.int32))
-
     def _form(self, batch) -> Dict[str, jax.Array]:
         # Kernel imports stay lazy: token-path users of this module never
         # pay for building the Pallas kernels.
@@ -193,18 +201,25 @@ class ImageFeed:
         from repro.kernels.ref import crop_mirror_normalize_np
 
         B = len(batch.samples)
-        oy, ox, mirror = self._augment_draws(B)
+        oy, ox, mirror = augment_draws(self._rng, B, self.h, self.w,
+                                       self.out_h, self.out_w)
         labels = batch.labels
         if self.mode == "arena":
             t0 = time.perf_counter()
             pix = batch.pixels(self.h, self.w, self.c)   # zero-copy view
             img_dev = jax.device_put(pix)                # ONE uint8 upload
             self.host_prep_s += time.perf_counter() - t0
-            batch.release()          # slab uploaded; recycle it
             images = kernel_ops.crop_mirror_normalize(
                 img_dev, jnp.asarray(oy), jnp.asarray(ox),
                 jnp.asarray(mirror), jnp.asarray(self.mean),
-                jnp.asarray(self.std), out_h=self.out_h, out_w=self.out_w)
+                jnp.asarray(self.inv_std), out_h=self.out_h, out_w=self.out_w,
+                interpret=self.interpret)
+            # The loader refills a released slab with the next batch.  The
+            # upload is asynchronous (and on the CPU backend img_dev may be
+            # the slab itself), so recycle it only once the kernel that
+            # reads it is done.
+            images.block_until_ready()
+            batch.release()
         else:
             t0 = time.perf_counter()
             n = self.h * self.w * self.c
@@ -213,7 +228,7 @@ class ImageFeed:
                               count=n).reshape(self.h, self.w, self.c)
                 for p in batch.payloads()])
             host = crop_mirror_normalize_np(
-                imgs, oy, ox, mirror, self.mean, self.std,
+                imgs, oy, ox, mirror, self.mean, self.inv_std,
                 self.out_h, self.out_w)
             images = jax.device_put(host)
             self.host_prep_s += time.perf_counter() - t0
@@ -252,4 +267,4 @@ class ImageFeed:
         return dev_batch, meta
 
 
-__all__ = ["DeviceFeed", "ImageFeed", "batch_to_numpy"]
+__all__ = ["DeviceFeed", "ImageFeed", "augment_draws", "batch_to_numpy"]

@@ -59,7 +59,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                  lengths: jax.Array, *, block_k: int = 512,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool = False) -> jax.Array:
     """q (B,K,G,D); k,v (B,K,T,D); lengths (B,) -> (B,K,G,D)."""
     B, K, G, D = q.shape
     T = k.shape[2]

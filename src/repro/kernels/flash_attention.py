@@ -79,7 +79,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """q (B,H,S,D); k,v (B,K,T,D); H % K == 0. Returns (B,H,S,D)."""
     B, H, S, D = q.shape
     K, T = k.shape[1], k.shape[2]

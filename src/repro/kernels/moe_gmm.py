@@ -35,7 +35,7 @@ def _gmm_kernel(x_ref, w_ref, o_ref, acc_scr):
 
 def grouped_matmul(x: jax.Array, w: jax.Array, *, block_c: int = 128,
                    block_f: int = 128, block_d: int = 512,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool = False) -> jax.Array:
     """x (E,C,d) @ w (E,d,f) -> (E,C,f)."""
     E, C, d = x.shape
     f = w.shape[2]

@@ -1,8 +1,8 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only — the
-kernels execute through the Pallas interpreter for correctness validation)
-and to False on a real TPU backend.
+The kernels compile for the TPU.  ``interpret=True`` runs them through the
+Pallas interpreter instead; only a caller that wants that (the CPU tests,
+a CPU benchmark run) passes it — the wrappers never pick a mode on their own.
 """
 
 from __future__ import annotations
@@ -18,45 +18,33 @@ from .flash_attention import flash_attention as _flash_attention
 from .moe_gmm import grouped_matmul as _gmm
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,
-                    block_k=128, interpret=None):
-    if interpret is None:
-        interpret = _default_interpret()
+                    block_k=128, interpret: bool = False):
     return _flash_attention(q, k, v, causal=causal, window=window,
                             block_q=block_q, block_k=block_k,
                             interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def flash_decode(q, k, v, lengths, *, block_k=512, interpret=None):
-    if interpret is None:
-        interpret = _default_interpret()
+def flash_decode(q, k, v, lengths, *, block_k=512, interpret: bool = False):
     return _flash_decode(q, k, v, lengths, block_k=block_k,
                          interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("out_h", "out_w", "dtype",
                                              "interpret"))
-def crop_mirror_normalize(img, oy, ox, mirror, mean, std, *, out_h, out_w,
-                          dtype=jnp.float32, interpret=None):
-    if interpret is None:
-        interpret = _default_interpret()
-    return _cmn(img, oy, ox, mirror, mean, std, out_h, out_w, dtype,
+def crop_mirror_normalize(img, oy, ox, mirror, mean, inv_std, *, out_h, out_w,
+                          dtype=jnp.float32, interpret: bool = False):
+    return _cmn(img, oy, ox, mirror, mean, inv_std, out_h, out_w, dtype,
                 interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "block_f", "block_d",
                                              "interpret"))
 def grouped_matmul(x, w, *, block_c=128, block_f=128, block_d=512,
-                   interpret=None):
-    if interpret is None:
-        interpret = _default_interpret()
+                   interpret: bool = False):
     return _gmm(x, w, block_c=block_c, block_f=block_f, block_d=block_d,
                 interpret=interpret)
 

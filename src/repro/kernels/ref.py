@@ -4,8 +4,6 @@ baseline transform in ``data.pipeline.ImageFeed``."""
 
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -54,44 +52,47 @@ def decode_reference(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def crop_mirror_normalize_reference(img: jax.Array, oy: jax.Array,
                                     ox: jax.Array, mirror: jax.Array,
-                                    mean: jax.Array, std: jax.Array,
+                                    mean: jax.Array, inv_std: jax.Array,
                                     out_h: int, out_w: int,
                                     dtype=jnp.float32) -> jax.Array:
     """img (B,H,W,C) uint8 -> (B,C,out_h,out_w), DALI crop_mirror_normalize.
 
-    oy/ox (B,) crop offsets, mirror (B,) bool, mean/std (C,) in 0..255 scale.
+    oy/ox (B,) crop offsets, mirror (B,) bool, mean/inv_std (C,) in 0..255
+    scale: ``(crop - mean) * inv_std``.
     """
     def one(im, y, x, m):
         crop = jax.lax.dynamic_slice(im, (y, x, 0),
                                      (out_h, out_w, im.shape[2]))
         crop = jnp.where(m, crop[:, ::-1, :], crop)
-        out = (crop.astype(jnp.float32) - mean) / std
+        out = (crop.astype(jnp.float32) - mean) * inv_std
         return out.transpose(2, 0, 1).astype(dtype)
 
     return jax.vmap(one)(img, oy, ox, mirror)
 
 
 def crop_mirror_normalize_np(img: np.ndarray, oy, ox, mirror,
-                             mean: np.ndarray, std: np.ndarray,
+                             mean: np.ndarray, inv_std: np.ndarray,
                              out_h: int, out_w: int,
                              dtype=np.float32) -> np.ndarray:
     """NumPy twin of the Pallas kernel: (B,H,W,C) uint8 -> (B,C,oh,ow).
 
     Same clamping semantics as the kernel entry point (offsets clip to the
-    valid window).  Also serves as ``ImageFeed``'s materialize-path host
-    transform — the four-pass CPU pipeline the fused kernel replaces.
+    valid window) and the same float32 arithmetic, ``(crop - mean) *
+    inv_std``, so the two agree bit for bit.  Also serves as ``ImageFeed``'s
+    materialize-path host transform — the four-pass CPU pipeline the fused
+    kernel replaces.
     """
     B, H, W, C = img.shape
     oy = np.clip(np.asarray(oy, dtype=np.int64), 0, H - out_h)
     ox = np.clip(np.asarray(ox, dtype=np.int64), 0, W - out_w)
     mean = np.asarray(mean, dtype=np.float32)
-    std = np.asarray(std, dtype=np.float32)
+    inv_std = np.asarray(inv_std, dtype=np.float32)
     out = np.empty((B, C, out_h, out_w), dtype=dtype)
     for i in range(B):
         crop = img[i, oy[i]:oy[i] + out_h, ox[i]:ox[i] + out_w, :]
         if mirror[i]:
             crop = crop[:, ::-1, :]
-        x = (crop.astype(np.float32) - mean) / std
+        x = (crop.astype(np.float32) - mean) * inv_std
         out[i] = x.transpose(2, 0, 1).astype(dtype)
     return out
 

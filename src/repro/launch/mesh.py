@@ -13,15 +13,22 @@ from __future__ import annotations
 import jax
 
 
+def _auto(n: int) -> tuple:
+    # the sharding rules place arrays with with_sharding_constraint, which
+    # needs Auto axes (jax.make_mesh defaults to Explicit ones)
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 4):
     """Small mesh for CI-scale sharding tests (8 host devices)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 # TPU v5e hardware constants (per chip) — used by the roofline analysis.

@@ -8,6 +8,11 @@ arrays are device_put against the new shardings.
 
 The loader position (epoch, cursor) is stored in the manifest, making
 mid-epoch restart exact at batch granularity (see core/prefetcher.state).
+
+``np.savez`` cannot store dtypes NumPy does not know (bfloat16, float8):
+it writes them as opaque ``|V2`` records that no cast reads back.  Such
+leaves are saved as a same-width unsigned-int bit view, with the dtype's
+name in the manifest, and viewed back on restore — bit-exact.
 """
 
 from __future__ import annotations
@@ -20,6 +25,7 @@ import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 
@@ -30,6 +36,17 @@ def _flatten_with_paths(tree: Any) -> Dict[str, np.ndarray]:
                        for p in path)
         flat[key] = np.asarray(leaf)
     return flat
+
+
+def _bit_views(flat: Dict[str, np.ndarray]) -> Dict[str, str]:
+    """Replace leaves of dtypes NumPy cannot save (kind ``V``) by unsigned
+    bit views, in place; returns ``{key: dtype name}`` for those leaves."""
+    viewed = {}
+    for key, arr in flat.items():
+        if arr.dtype.kind == "V":
+            viewed[key] = arr.dtype.name
+            flat[key] = arr.view(f"u{arr.dtype.itemsize}")
+    return viewed
 
 
 class CheckpointManager:
@@ -45,7 +62,8 @@ class CheckpointManager:
         # Snapshot to host memory synchronously (cheap), write async if asked.
         flat = _flatten_with_paths(state)
         manifest = {"step": int(step), "time": time.time(),
-                    "keys": sorted(flat.keys()), "extra": extra or {}}
+                    "keys": sorted(flat.keys()), "dtypes": _bit_views(flat),
+                    "extra": extra or {}}
 
         def write():
             final = os.path.join(self.directory, f"step_{step:08d}")
@@ -107,6 +125,7 @@ class CheckpointManager:
         with open(os.path.join(path, "manifest.json")) as f:
             manifest = json.load(f)
         data = np.load(os.path.join(path, "arrays.npz"))
+        viewed = manifest.get("dtypes", {})
 
         leaves_t, treedef = jax.tree_util.tree_flatten(template)
         paths = [
@@ -120,6 +139,8 @@ class CheckpointManager:
             if key not in data:
                 raise KeyError(f"checkpoint missing key {key}")
             arr = data[key]
+            if key in viewed:
+                arr = arr.view(jnp.dtype(viewed[key]))
             if tuple(arr.shape) != tuple(tmpl.shape):
                 raise ValueError(f"shape mismatch for {key}: "
                                  f"{arr.shape} vs {tmpl.shape}")

@@ -14,7 +14,6 @@ import time
 from typing import Callable, Dict, Optional
 
 import jax
-import numpy as np
 
 from repro.core import CassandraLoader, KVStore, LoaderConfig, VirtualClock
 from repro.data.pipeline import DeviceFeed
@@ -47,8 +46,9 @@ def run_training(model, store: KVStore, uuids, loader_cfg: LoaderConfig,
     """Train `model` from the network loader.
 
     Returns ``{"state", "history", "stats", "step_stats"}`` — history
-    records carry ``loss``/``sps`` plus per-step data-stall accounting
-    (``stall_frac``, ``goodput_sps``), ``stats`` is the
+    records carry ``loss``/``sps``, the host-clock ``step_s`` of that step
+    (its jitted call through ``block_until_ready``) plus per-step data-stall
+    accounting (``stall_frac``, ``goodput_sps``), ``stats`` is the
     ``StepStats.summary`` at skip=1 (the jit-compile step excluded) and
     ``step_stats`` the raw ``core.stats.StepStats`` for custom skips.
     """
@@ -61,7 +61,10 @@ def run_training(model, store: KVStore, uuids, loader_cfg: LoaderConfig,
     loader_pos = {"epoch": 0, "cursor": 0}
     if state is None:
         if ckpt and ckpt.latest_step() is not None:
-            template = init_state(model, opt_cfg, jax.random.PRNGKey(loop_cfg.seed))
+            # shapes only: a materialized template would hold a second copy
+            # of the state on the device for the whole run
+            template = jax.eval_shape(lambda: init_state(
+                model, opt_cfg, jax.random.PRNGKey(loop_cfg.seed)))
             state, manifest = ckpt.restore(template)
             start_step = manifest["step"]
             loader_pos = manifest["extra"].get("loader", loader_pos)
@@ -99,7 +102,7 @@ def run_training(model, store: KVStore, uuids, loader_cfg: LoaderConfig,
         c0 = time.perf_counter()
         state, metrics = step_fn(state, batch)
         jax.block_until_ready(metrics["loss"])
-        compute = time.perf_counter() - c0
+        compute = step_s = time.perf_counter() - c0
         if loop_cfg.charge_step_time is not None:
             compute = loop_cfg.charge_step_time
         if virtual:
@@ -111,7 +114,7 @@ def run_training(model, store: KVStore, uuids, loader_cfg: LoaderConfig,
             t0 = time.time()
         if (step + 1) % loop_cfg.log_every == 0 or step == start_step:
             loss = float(metrics["loss"])
-            rec = {"step": step + 1, "loss": loss,
+            rec = {"step": step + 1, "loss": loss, "step_s": step_s,
                    "sps": (step - start_step) * B
                    / max(time.time() - t0, 1e-9),
                    "stall_frac": ss.stall_frac(skip=1),

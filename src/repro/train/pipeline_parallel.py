@@ -7,42 +7,16 @@ bubble fraction (S-1)/(M+S-1) with M microbatches.
 
 This is an optional parallelism mode (the production mesh in this repo uses
 DPxTP(+SP); PP composes on top when depth x width exceeds a pod), exercised
-by tests/test_pipeline_parallel.py on an 8-device host mesh.
+by tests/test_multidevice.py on an 8-device host mesh.
 """
 
 from __future__ import annotations
 
-import functools
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """jax.shard_map (>=0.5) or the experimental spelling (0.4.x)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as esm
-    # the scan carry is device-varying after ppermute; the 0.4.x replication
-    # checker cannot see that, so it must be disabled rather than pcast-ed.
-    return esm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def _mark_varying(x, axis):
-    """Mark a scan carry device-varying: jax.lax.pcast (some versions) or
-    jax.lax.pvary (newer); 0.4.x has no such notion (check_rep=False above
-    covers it)."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, (axis,), to="varying")
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        return pvary(x, (axis,))
-    return x
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def pipeline_forward(stage_fn: Callable, n_stages: int, n_microbatches: int,
@@ -91,8 +65,8 @@ def pipeline_forward(stage_fn: Callable, n_stages: int, n_microbatches: int,
             buf0 = jnp.zeros_like(x_local[0])
             out0 = jnp.zeros_like(x_local)
             # the carry becomes device-varying after ppermute: mark it so
-            buf0 = _mark_varying(buf0, axis)
-            out0 = _mark_varying(out0, axis)
+            buf0 = jax.lax.pcast(buf0, (axis,), to="varying")
+            out0 = jax.lax.pcast(out0, (axis,), to="varying")
             (_, outputs), _ = jax.lax.scan(tick, (buf0, out0),
                                            jnp.arange(T))
             # only stage S-1 holds real outputs; broadcast via psum of masked
@@ -100,7 +74,7 @@ def pipeline_forward(stage_fn: Callable, n_stages: int, n_microbatches: int,
                 jnp.where(stage_id == S - 1, outputs, 0.0), axis)
             return outputs
 
-        return _shard_map(
+        return jax.shard_map(
             per_stage, mesh=mesh,
             in_specs=(P(axis), P()),
             out_specs=P(),

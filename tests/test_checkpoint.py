@@ -73,8 +73,34 @@ def test_elastic_restore_with_shardings(tmp_path):
     mgr = CheckpointManager(str(tmp_path))
     state = _state()
     mgr.save(3, state)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, PartitionSpec()), state)
     restored, _ = mgr.restore(state, shardings=sh)
     np.testing.assert_array_equal(np.asarray(restored["params"]["w"]),
                                   np.asarray(state["params"]["w"]))
+
+
+def test_bf16_state_restores_bit_exact(tmp_path):
+    """A published config's state is bf16 params plus int8/f32 optimizer
+    leaves; every leaf comes back with its dtype and its exact bits."""
+    from repro.configs.base import get_arch
+    from repro.models import build_model
+    from repro.train.optimizer import OptimizerConfig
+    from repro.train.step import init_state
+
+    cfg = get_arch("stablelm_1_6b").smoke_config().scaled(dtype="bfloat16")
+    opt = OptimizerConfig(state_dtype="int8_factored")
+    state = init_state(build_model(cfg), opt, jax.random.PRNGKey(0))
+    assert {l.dtype for l in jax.tree.leaves(state["params"])} == {
+        jnp.dtype(jnp.bfloat16)}
+    mgr = CheckpointManager(str(tmp_path))
+    mgr.save(4, state)
+    template = jax.tree.map(jnp.zeros_like, state)
+    restored, manifest = mgr.restore(template)
+    assert manifest["step"] == 4
+    for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(restored)):
+        assert a.dtype == b.dtype
+        a, b = np.asarray(a), np.asarray(b)
+        np.testing.assert_array_equal(a.view(f"u{a.itemsize}"),
+                                      b.view(f"u{b.itemsize}"))

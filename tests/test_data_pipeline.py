@@ -76,3 +76,40 @@ def test_per_host_sharding_is_partition(token_store):
         seen.extend(str(u) for u in ld.plan._uuids)
     assert len(seen) == len(uuids)
     assert set(seen) == {str(u) for u in uuids}
+
+
+def test_image_feed_arena_matches_numpy_reference():
+    """build_stack's image feed on the arena path: every batch equals the
+    NumPy transform of the store's own bytes under replayed augmentation
+    draws, bit for bit, while arena slabs are recycled between batches."""
+    from repro.core import build_stack
+    from repro.data.datasets import SyntheticPixelDataset
+    from repro.data.pipeline import augment_draws
+    from repro.kernels.ref import crop_mirror_normalize_np
+
+    ds = SyntheticPixelDataset(n_samples=96, h=32, w=32, c=3, seed=2)
+    store = KVStore()
+    uuids = ingest(store, ds)
+    B, out = 8, 24
+    stack = build_stack(
+        store=store, uuids=uuids,
+        config=LoaderConfig(batch_size=B, route="high", materialize=True,
+                            use_arena=True, arena_slot_bytes=ds.nbytes,
+                            seed=1),
+        feed="image", image_shape=(ds.h, ds.w, ds.c), out_shape=(out, out),
+        mean=[123.7, 116.3, 103.5], std=[58.4, 57.1, 57.4], feed_seed=9,
+        interpret=True)
+    feed = stack.feed
+    rng = np.random.default_rng(9)
+    for _ in range(5):
+        dev, meta = next(feed)
+        oy, ox, mirror = augment_draws(rng, B, ds.h, ds.w, out, out)
+        pixels = np.stack([
+            np.frombuffer(store.get_data(u).payload, dtype=np.uint8
+                          ).reshape(ds.h, ds.w, ds.c) for u in meta.uuids])
+        want = crop_mirror_normalize_np(pixels, oy, ox, mirror, feed.mean,
+                                        feed.inv_std, out, out)
+        np.testing.assert_array_equal(np.asarray(dev["images"]), want)
+        np.testing.assert_array_equal(np.asarray(dev["labels"]), meta.labels)
+    assert stack.loader.arena.stats()["reuses"] > 0
+    stack.close()

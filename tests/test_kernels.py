@@ -26,7 +26,8 @@ def test_flash_attention_sweep(dtype, B, H, K, S, D, bq, bk):
     q = jax.random.normal(ks[0], (B, H, S, D), dtype)
     k = jax.random.normal(ks[1], (B, K, S, D), dtype)
     v = jax.random.normal(ks[2], (B, K, S, D), dtype)
-    got = ops.flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
+    got = ops.flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
+                              interpret=True)
     want = ref.mha_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
@@ -39,7 +40,7 @@ def test_flash_attention_sliding_window(window):
     k = jax.random.normal(ks[1], (1, 2, 256, 32))
     v = jax.random.normal(ks[2], (1, 2, 256, 32))
     got = ops.flash_attention(q, k, v, causal=True, window=window,
-                              block_q=64, block_k=64)
+                              block_q=64, block_k=64, interpret=True)
     want = ref.mha_reference(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -57,7 +58,7 @@ def test_flash_decode_sweep(dtype, B, K, G, T, D, bk):
     k = jax.random.normal(ks[1], (B, K, T, D), dtype)
     v = jax.random.normal(ks[2], (B, K, T, D), dtype)
     lengths = jax.random.randint(ks[3], (B,), 1, T + 1)
-    got = ops.flash_decode(q, k, v, lengths, block_k=bk)
+    got = ops.flash_decode(q, k, v, lengths, block_k=bk, interpret=True)
     want = ref.decode_reference(q.reshape(B, K * G, D), k, v, lengths)
     np.testing.assert_allclose(np.asarray(got.reshape(B, K * G, D), np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
@@ -73,11 +74,11 @@ def test_crop_mirror_normalize_property(oy, ox, mirror, out_h, out_w):
     oxs = jnp.array([ox, (ox + 3) % 16])
     mir = jnp.array([mirror, not mirror])
     mean = jnp.array([120.0, 115.0, 100.0])
-    std = jnp.array([60.0, 61.0, 62.0])
-    got = ops.crop_mirror_normalize(img, oys, oxs, mir, mean, std,
-                                    out_h=out_h, out_w=out_w)
-    want = ref.crop_mirror_normalize_reference(img, oys, oxs, mir, mean, std,
-                                               out_h, out_w)
+    inv_std = 1.0 / jnp.array([60.0, 61.0, 62.0])
+    got = ops.crop_mirror_normalize(img, oys, oxs, mir, mean, inv_std,
+                                    out_h=out_h, out_w=out_w, interpret=True)
+    want = ref.crop_mirror_normalize_reference(img, oys, oxs, mir, mean,
+                                               inv_std, out_h, out_w)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
 
@@ -98,13 +99,14 @@ def test_crop_mirror_normalize_matches_numpy_ref(seed, mirror, out_h, out_w):
     ox = rng.integers(0, W - out_w + 1, size=B).astype(np.int32)
     mir = np.array([mirror, not mirror, mirror], dtype=np.int32)
     mean = np.array([120.0, 115.0, 100.0], dtype=np.float32)
-    std = np.array([60.0, 61.0, 62.0], dtype=np.float32)
+    inv_std = np.float32(1) / np.array([60.0, 61.0, 62.0], dtype=np.float32)
     got = ops.crop_mirror_normalize(
         jnp.asarray(img), jnp.asarray(oy), jnp.asarray(ox), jnp.asarray(mir),
-        jnp.asarray(mean), jnp.asarray(std), out_h=out_h, out_w=out_w)
-    want = ref.crop_mirror_normalize_np(img, oy, ox, mir, mean, std,
+        jnp.asarray(mean), jnp.asarray(inv_std), out_h=out_h, out_w=out_w,
+        interpret=True)
+    want = ref.crop_mirror_normalize_np(img, oy, ox, mir, mean, inv_std,
                                         out_h, out_w)
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 def test_crop_mirror_normalize_clamps_offsets():
@@ -116,13 +118,14 @@ def test_crop_mirror_normalize_clamps_offsets():
     ox = np.array([-3, 99], dtype=np.int32)
     mir = np.zeros(2, dtype=np.int32)
     mean = np.zeros(3, dtype=np.float32)
-    std = np.ones(3, dtype=np.float32)
+    inv_std = np.ones(3, dtype=np.float32)
     got = ops.crop_mirror_normalize(
         jnp.asarray(img), jnp.asarray(oy), jnp.asarray(ox), jnp.asarray(mir),
-        jnp.asarray(mean), jnp.asarray(std), out_h=8, out_w=8)
-    want = ref.crop_mirror_normalize_np(img, oy, ox, mir, mean, std, 8, 8)
+        jnp.asarray(mean), jnp.asarray(inv_std), out_h=8, out_w=8,
+        interpret=True)
+    want = ref.crop_mirror_normalize_np(img, oy, ox, mir, mean, inv_std, 8, 8)
     clamped = ref.crop_mirror_normalize_np(
-        img, np.array([8, 0]), np.array([0, 8]), mir, mean, std, 8, 8)
+        img, np.array([8, 0]), np.array([0, 8]), mir, mean, inv_std, 8, 8)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=1e-6)
     np.testing.assert_allclose(want, clamped, rtol=1e-6, atol=1e-6)
 
@@ -137,7 +140,8 @@ def test_grouped_matmul_sweep(dtype, E, C, d, f, bc, bf, bd):
     ks = jax.random.split(jax.random.PRNGKey(4), 2)
     x = jax.random.normal(ks[0], (E, C, d), dtype)
     w = jax.random.normal(ks[1], (E, d, f), dtype)
-    got = ops.grouped_matmul(x, w, block_c=bc, block_f=bf, block_d=bd)
+    got = ops.grouped_matmul(x, w, block_c=bc, block_f=bf, block_d=bd,
+                             interpret=True)
     want = ref.gmm_reference(x, w)
     tol = dict(rtol=5e-2, atol=5e-1) if dtype == jnp.bfloat16 \
         else dict(rtol=1e-4, atol=1e-4)
@@ -157,6 +161,7 @@ def test_flash_attention_matches_model_chunked_path():
     pallas = ops.flash_attention(q.transpose(0, 2, 1, 3),
                                  k.transpose(0, 2, 1, 3),
                                  v.transpose(0, 2, 1, 3),
-                                 causal=True, block_q=64, block_k=64)
+                                 causal=True, block_q=64, block_k=64,
+                                 interpret=True)
     np.testing.assert_allclose(np.asarray(pallas.transpose(0, 2, 1, 3)),
                                np.asarray(xla), rtol=2e-5, atol=2e-5)

@@ -11,6 +11,7 @@ import textwrap
 import pytest
 
 ENV = dict(os.environ,
+           JAX_PLATFORMS="cpu",
            XLA_FLAGS="--xla_force_host_platform_device_count=8",
            PYTHONPATH=os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -29,7 +30,8 @@ def test_pipeline_parallel_matches_sequential():
         from repro.train.pipeline_parallel import (pipeline_forward,
                                                    stack_stage_params)
         S, M = 4, 8                      # stages, microbatches
-        mesh = jax.make_mesh((S,), ("stage",))
+        mesh = jax.make_mesh((S,), ("stage",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         L, d = 8, 16
         key = jax.random.PRNGKey(0)
         w = jax.random.normal(key, (L, d, d)) * 0.2
@@ -65,11 +67,9 @@ def test_compressed_psum_error_feedback_converges():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.train.compression import compressed_psum_grads
-        try:
-            from jax import shard_map
-        except ImportError:                      # jax 0.4.x spelling
-            from jax.experimental.shard_map import shard_map
-        mesh = jax.make_mesh((8,), ("data",))
+        from jax import shard_map
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         grads = {"w": jax.random.normal(jax.random.PRNGKey(0), (8, 4, 16))}
         errors = {"w": jnp.zeros((8, 4, 16))}
 
@@ -106,7 +106,8 @@ def test_dryrun_cell_compiles_small_mesh(arch, shape):
                     for k, v in B.SHAPES.items()}}
         import repro.launch.dryrun_lib as D
         D.SHAPES = B.SHAPES
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         res = D.run_cell("{arch}", "{shape}", mesh, verbose=False)
         assert res["flops_per_device"] > 0
         assert res["memory"]["temp_bytes"] >= 0

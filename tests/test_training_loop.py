@@ -299,3 +299,48 @@ def test_flow_snapshot_none_in_static_mode(token_store):
     loader_a = _loader(token_store, flow_control="adaptive")
     snap = loader_a.flow_snapshot()
     assert isinstance(snap, dict) and "budget" in snap
+
+
+def test_launcher_resumes_from_its_checkpoint(tmp_path, capsys,
+                                              monkeypatch):
+    """``repro.launch.train.main(argv)`` in-process: a run to step 2 leaves
+    a checkpoint, and a run to step 4 resumes from it at step 2."""
+    from repro.launch.train import main
+
+    # a set variable makes the launcher leave JAX's cache settings alone
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+
+    argv = ["--arch", "stablelm_1_6b", "--smoke", "--batch-size", "2",
+            "--seq-len", "32", "--opt-state-dtype", "int8_factored",
+            "--log-every", "1", "--checkpoint-dir", str(tmp_path / "ckpt")]
+    first = main(argv + ["--steps", "2"])
+    second = main(argv + ["--steps", "4"])
+    assert [r["step"] for r in first["history"]] == [1, 2]
+    assert [r["step"] for r in second["history"]] == [3, 4]
+    assert all(np.isfinite(r["loss"]) and r["step_s"] > 0
+               for r in first["history"] + second["history"])
+    assert "stablelm-1.6b: loss" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir_is_fixed(env_dir, tmp_path, monkeypatch):
+    """The entry points' cache is $JAX_COMPILATION_CACHE_DIR when set (JAX
+    reads it itself; nothing else is set), else <checkout>/.jax_cache."""
+    import jax
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(compile_cache.CHECKOUT, ".jax_cache")
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    try:
+        assert compile_cache.enable_compile_cache() == want
+        assert os.path.isfile(os.path.join(compile_cache.CHECKOUT, "ROADMAP.md"))
+        assert jax.config.jax_compilation_cache_dir == (
+            want if env_dir is None else before)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
