@@ -19,6 +19,7 @@ import numpy as np
 from .arena import ArenaSlab, PinnedArena
 from .connection import ConnectionPool, FetchResult
 from .netsim import Clock
+from .stats import span
 
 HOST_COPY_BANDWIDTH = 20.0e9  # bytes/s, multi-threaded memcpy into the arena
 
@@ -104,23 +105,25 @@ class BatchAssembler:
         self.bytes_assembled += nbytes
         slab = None
         if self._real_copy and self._arena is not None:
-            slab = self._arena.acquire()
-            for i, s in enumerate(samples):
-                slab.write(i, s.payload, s.size)
-                s.payload = None       # the slab owns the bytes now
+            with span("loader.assemble", batch=seq):
+                slab = self._arena.acquire()
+                for i, s in enumerate(samples):
+                    slab.write(i, s.payload, s.size)
+                    s.payload = None       # the slab owns the bytes now
         elif self._real_copy:
             # Legacy one-shot bytearray; copies are cheap at test scale.
             # Each sample owns exactly ``size`` bytes (payloads are
             # full-size since DataRow.materialize stopped truncating — clip
             # defensively so a short payload can never smear into its
             # neighbour's slot).
-            arena = bytearray(nbytes)
-            off = 0
-            for s in samples:
-                if s.payload is not None:
-                    n = min(len(s.payload), s.size)
-                    arena[off:off + n] = s.payload[:n]
-                off += s.size
+            with span("loader.assemble", batch=seq):
+                arena = bytearray(nbytes)
+                off = 0
+                for s in samples:
+                    if s.payload is not None:
+                        n = min(len(s.payload), s.size)
+                        arena[off:off + n] = s.payload[:n]
+                    off += s.size
         delay = nbytes / self._copy_bw
         batch = AssembledBatch(seq=seq, samples=list(samples),
                                t_first_issue=min(s.t_issued for s in samples),

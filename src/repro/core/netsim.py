@@ -30,6 +30,7 @@ from typing import Callable, List, Optional, Tuple
 
 import numpy as np
 
+from . import stats as _stats
 from .stats import windowed_series
 
 # ---------------------------------------------------------------------------
@@ -269,8 +270,23 @@ class VirtualClock(Clock):
                 raise RuntimeError("virtual clock drain exceeded event budget")
 
 
+def _fire(fn: Callable, args: tuple) -> None:
+    try:
+        fn(*args)
+    except Exception:  # pragma: no cover - surfaced via stats in tests
+        import traceback
+
+        traceback.print_exc()
+
+
 class RealClock(Clock):
-    """Wall-clock implementation backed by a timer thread."""
+    """Wall-clock implementation backed by a timer thread.
+
+    While a span recorder is installed (``core.stats.enable``) the thread
+    keeps three running sums in it: ``clock.events`` fired,
+    ``clock.busy_s`` spent inside them and ``clock.lag_s``, each event's
+    fire time less its due time.
+    """
 
     def __init__(self) -> None:
         self._heap: list = []
@@ -303,12 +319,16 @@ class RealClock(Clock):
                     self._cv.wait(timeout=min(dt, 0.05))
                     continue
                 heapq.heappop(self._heap)
-            try:
-                fn(*args)
-            except Exception:  # pragma: no cover - surfaced via stats in tests
-                import traceback
-
-                traceback.print_exc()
+            rec = _stats.active
+            if rec is None:
+                _fire(fn, args)
+            else:
+                lag = self.now() - t
+                t0 = _time.perf_counter()
+                _fire(fn, args)
+                rec.count("clock.busy_s", _time.perf_counter() - t0)
+                rec.count("clock.lag_s", lag)
+                rec.count("clock.events")
             with self._cv:
                 self._cv.notify_all()
 

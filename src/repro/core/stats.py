@@ -1,12 +1,19 @@
-"""Instrumentation: batch-time series, throughput windows, epoch summaries.
+"""Instrumentation: batch-time series, throughput windows, epoch summaries,
+and the span recorder.
 
-Produces the raw material for the paper's Figs. 4-7 and Tables 3-4.
+Produces the raw material for the paper's Figs. 4-7 and Tables 3-4.  The
+recorder (``enable`` / ``span`` / ``count``) times the host work of one
+batch or step in named spans and keeps running sums of the simulator's
+event thread; it costs one ``is None`` test per site while nothing is
+installed.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import List, Optional, Sequence, Tuple
+import itertools
+import threading
+import time
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -51,17 +58,9 @@ class LoaderStats:
         self.batch_consume_t: List[float] = []
         self.batch_nbytes: List[int] = []
         self.batch_wait: List[float] = []      # consumer-visible wait per batch
-        self.sample_arrive_t: List[float] = []
-        self.issues: List[tuple] = []
         self._last_consume: Optional[float] = None
 
     # -- hooks -------------------------------------------------------------
-    def on_issue(self, seq: int, n: int) -> None:
-        self.issues.append((self._clock.now(), seq, n))
-
-    def on_sample(self, res) -> None:
-        self.sample_arrive_t.append(res.t_done)
-
     def on_batch_ready(self, batch) -> None:
         self.batch_ready_t.append(batch.t_ready)
 
@@ -186,6 +185,190 @@ class StepStats:
         }
 
 
+# -- spans and counters ------------------------------------------------------
+
+# The event JAX records around each backend compile (a persistent-cache
+# hit included), with the compiled function's name.
+JAX_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class Span(NamedTuple):
+    """One timed piece of host work, on ``time.perf_counter``."""
+
+    name: str
+    start: float
+    end: float
+    thread: int               # threading.get_ident() of the thread it ran on
+    id: int
+    parent: Optional[int]     # the enclosing span on the same thread
+    request: Optional[str]    # "batch=<seq>", "step=<n>", "fun_name=<f>"
+
+
+def _request(ids: Dict) -> Optional[str]:
+    return ",".join(f"{k}={v}" for k, v in ids.items()) or None
+
+
+class _NullSpan:
+    """What ``span`` hands back while nothing is installed: one shared
+    context that does nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def tag(self, **ids) -> None:
+        pass
+
+
+NULL_SPAN = _NullSpan()
+
+
+class _OpenSpan:
+    __slots__ = ("_rec", "_name", "_request", "_start", "_id", "_parent",
+                 "_stack")
+
+    def __init__(self, rec: "Recorder", name: str,
+                 request: Optional[str]) -> None:
+        self._rec = rec
+        self._name = name
+        self._request = request
+
+    def tag(self, **ids) -> None:
+        """Name the request once it is known (the batch a call pulled)."""
+        self._request = _request(ids)
+
+    def __enter__(self) -> "_OpenSpan":
+        stack = self._rec._stack()
+        self._parent = stack[-1] if stack else None
+        self._id = next(self._rec._ids)
+        stack.append(self._id)
+        self._stack = stack
+        self._start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        end = time.perf_counter()
+        self._stack.pop()
+        self._rec.spans.append(Span(self._name, self._start, end,
+                                    threading.get_ident(), self._id,
+                                    self._parent, self._request))
+        return False
+
+
+class Recorder:
+    """Spans and named running sums, kept in memory from ``enable`` to
+    ``disable``.
+
+    A span's parent is the span open around it on the same thread; its
+    request ties it to one batch (``batch=<AssembledBatch.seq>``) or one
+    train step (``step=<n>``), so a batch's assembly on the loader's event
+    thread joins its delivery on the consumer's.  JAX's backend compiles
+    arrive as ``jax.compile`` spans through a ``jax.monitoring`` listener.
+    """
+
+    def __init__(self) -> None:
+        self.spans: List[Span] = []
+        self.enabled_at = time.perf_counter()
+        self.disabled_at: Optional[float] = None
+        # JAX stamps its time spans with time.time()
+        self._wall_to_perf = self.enabled_at - time.time()
+        self._ids = itertools.count(1)
+        self._local = threading.local()
+        self._lock = threading.Lock()
+        self._sums: List[Dict[str, float]] = []     # one dict per thread
+
+    def _stack(self) -> List[int]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def span(self, name: str, **ids) -> _OpenSpan:
+        return _OpenSpan(self, name, _request(ids))
+
+    def count(self, name: str, amount: float = 1.0) -> None:
+        """Add to a running sum.  Each thread adds into sums of its own,
+        so a count takes no lock (the event thread counts every event)."""
+        sums = getattr(self._local, "sums", None)
+        if sums is None:
+            sums = self._local.sums = {}
+            with self._lock:
+                self._sums.append(sums)
+        sums[name] = sums.get(name, 0.0) + amount
+
+    @property
+    def counters(self) -> Dict[str, float]:
+        """Each running sum, over all threads."""
+        with self._lock:
+            parts = [list(sums.items()) for sums in self._sums]
+        out: Dict[str, float] = {}
+        for part in parts:
+            for name, value in part:
+                out[name] = out.get(name, 0.0) + value
+        return out
+
+    def _on_jax_span(self, event: str, start: float, end: float,
+                     **meta) -> None:
+        if event != JAX_COMPILE_EVENT:
+            return
+        stack = self._stack()
+        self.spans.append(Span(
+            "jax.compile", start + self._wall_to_perf,
+            end + self._wall_to_perf, threading.get_ident(),
+            next(self._ids), stack[-1] if stack else None,
+            _request({"fun_name": meta.get("fun_name", "?")})))
+
+    def totals(self) -> Dict[str, Tuple[int, float]]:
+        """Each span name's count and summed seconds."""
+        out: Dict[str, Tuple[int, float]] = {}
+        for s in self.spans:
+            n, secs = out.get(s.name, (0, 0.0))
+            out[s.name] = (n + 1, secs + s.end - s.start)
+        return out
+
+
+# The installed recorder; None while nothing records.  Sites read it once.
+active: Optional[Recorder] = None
+
+
+def enable() -> Recorder:
+    """Install a fresh recorder and start listening for JAX compiles."""
+    global active
+    if active is not None:
+        raise RuntimeError("a span recorder is already installed")
+    import jax
+
+    rec = Recorder()
+    jax.monitoring.register_event_time_span_listener(rec._on_jax_span)
+    active = rec
+    return rec
+
+
+def disable() -> Optional[Recorder]:
+    """Remove the installed recorder, if any, and hand it back."""
+    global active
+    rec, active = active, None
+    if rec is not None:
+        import jax
+
+        jax.monitoring.unregister_event_time_span_listener(rec._on_jax_span)
+        rec.disabled_at = time.perf_counter()
+    return rec
+
+
+def span(name: str, **ids):
+    """A span around the ``with`` block, in the installed recorder;
+    ``NULL_SPAN`` while none is installed."""
+    rec = active
+    if rec is None:
+        return NULL_SPAN
+    return rec.span(name, **ids)
+
+
 def summarize(values: np.ndarray) -> dict:
     if values.size == 0:
         return {"mean": 0.0, "std": 0.0, "p50": 0.0, "p99": 0.0, "max": 0.0}
@@ -195,4 +378,5 @@ def summarize(values: np.ndarray) -> dict:
             "max": float(values.max())}
 
 
-__all__ = ["LoaderStats", "StepStats", "summarize", "windowed_series"]
+__all__ = ["LoaderStats", "NULL_SPAN", "Recorder", "Span", "StepStats",
+           "disable", "enable", "span", "summarize", "windowed_series"]

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.loader import CassandraLoader
-from repro.core.stats import StepStats
+from repro.core.stats import StepStats, span
 from repro.data.datasets import decode_token_record
 
 
@@ -99,13 +99,18 @@ class DeviceFeed:
         ``(wait_seconds, buffer_hit)`` on the loader's clock."""
         hit = self.loader.ready_batches > 0
         clk = self.loader.clock
-        t0 = clk.now()
-        batch = self.loader.next_batch()
-        wait = clk.now() - t0
-        host = batch_to_numpy(batch, self.seq_len)
+        with span("feed.loader_wait") as sp:
+            t0 = clk.now()
+            batch = self.loader.next_batch()
+            wait = clk.now() - t0
+            sp.tag(batch=batch.seq)
+        with span("feed.decode", batch=batch.seq):
+            host = batch_to_numpy(batch, self.seq_len)
         # Host copy is complete: recycle the arena slab (no-op without one).
         batch.release()
-        self._queue.append((self._put(host), batch))
+        with span("feed.upload", batch=batch.seq):
+            dev = self._put(host)
+        self._queue.append((dev, batch))
         return wait, hit
 
     # -- checkpointing ------------------------------------------------------
@@ -118,19 +123,21 @@ class DeviceFeed:
         return self
 
     def __next__(self):
-        wait, hit = 0.0, True
-        if not self._started:
-            if not self.loader.started:
-                self.loader.start()
-            self._started = True
-            for _ in range(self.prefetch):
-                w, h = self._pull_one()
-                wait += w
-                hit = hit and h
-        dev_batch, meta = self._queue.popleft()
-        w, h = self._pull_one()              # refill behind the consumer
-        self.step_stats.on_wait(wait + w, blocked=not (hit and h))
-        return dev_batch, meta
+        with span("feed.next") as sp:
+            wait, hit = 0.0, True
+            if not self._started:
+                if not self.loader.started:
+                    self.loader.start()
+                self._started = True
+                for _ in range(self.prefetch):
+                    w, h = self._pull_one()
+                    wait += w
+                    hit = hit and h
+            dev_batch, meta = self._queue.popleft()
+            sp.tag(batch=meta.seq)
+            w, h = self._pull_one()              # refill behind the consumer
+            self.step_stats.on_wait(wait + w, blocked=not (hit and h))
+            return dev_batch, meta
 
 
 def augment_draws(rng: np.random.Generator, B: int, h: int, w: int,
@@ -204,22 +211,28 @@ class ImageFeed:
         oy, ox, mirror = augment_draws(self._rng, B, self.h, self.w,
                                        self.out_h, self.out_w)
         labels = batch.labels
+        seq = batch.seq
         if self.mode == "arena":
-            t0 = time.perf_counter()
-            pix = batch.pixels(self.h, self.w, self.c)   # zero-copy view
-            img_dev = jax.device_put(pix)                # ONE uint8 upload
-            self.host_prep_s += time.perf_counter() - t0
-            images = kernel_ops.crop_mirror_normalize(
-                img_dev, jnp.asarray(oy), jnp.asarray(ox),
-                jnp.asarray(mirror), jnp.asarray(self.mean),
-                jnp.asarray(self.inv_std), out_h=self.out_h, out_w=self.out_w,
-                interpret=self.interpret)
+            with span("feed.upload", batch=seq):
+                t0 = time.perf_counter()
+                pix = batch.pixels(self.h, self.w, self.c)   # zero-copy view
+                img_dev = jax.device_put(pix)                # ONE uint8 upload
+                self.host_prep_s += time.perf_counter() - t0
+            with span("feed.kernel", batch=seq):
+                images = kernel_ops.crop_mirror_normalize(
+                    img_dev, jnp.asarray(oy), jnp.asarray(ox),
+                    jnp.asarray(mirror), jnp.asarray(self.mean),
+                    jnp.asarray(self.inv_std), out_h=self.out_h,
+                    out_w=self.out_w, interpret=self.interpret)
             # The loader refills a released slab with the next batch.  The
             # upload is asynchronous (and on the CPU backend img_dev may be
             # the slab itself), so recycle it only once the kernel that
             # reads it is done.
-            images.block_until_ready()
-            batch.release()
+            with span("feed.kernel_wait", batch=seq):
+                images.block_until_ready()
+            with span("feed.release", batch=seq):
+                batch.release()
+                labels_dev = jax.device_put(labels)
         else:
             t0 = time.perf_counter()
             n = self.h * self.w * self.c
@@ -232,15 +245,18 @@ class ImageFeed:
                 self.out_h, self.out_w)
             images = jax.device_put(host)
             self.host_prep_s += time.perf_counter() - t0
+            labels_dev = jax.device_put(labels)
         self.batches += 1
-        return {"images": images, "labels": jax.device_put(labels)}
+        return {"images": images, "labels": labels_dev}
 
     def _pull_one(self) -> tuple:
         hit = self.loader.ready_batches > 0
         clk = self.loader.clock
-        t0 = clk.now()
-        batch = self.loader.next_batch()
-        wait = clk.now() - t0
+        with span("feed.loader_wait") as sp:
+            t0 = clk.now()
+            batch = self.loader.next_batch()
+            wait = clk.now() - t0
+            sp.tag(batch=batch.seq)
         self._queue.append((self._form(batch), batch))
         return wait, hit
 
@@ -252,19 +268,21 @@ class ImageFeed:
         return self
 
     def __next__(self):
-        wait, hit = 0.0, True
-        if not self._started:
-            if not self.loader.started:
-                self.loader.start()
-            self._started = True
-            for _ in range(self.prefetch):
-                w, h = self._pull_one()
-                wait += w
-                hit = hit and h
-        dev_batch, meta = self._queue.popleft()
-        w, h = self._pull_one()              # refill behind the consumer
-        self.step_stats.on_wait(wait + w, blocked=not (hit and h))
-        return dev_batch, meta
+        with span("feed.next") as sp:
+            wait, hit = 0.0, True
+            if not self._started:
+                if not self.loader.started:
+                    self.loader.start()
+                self._started = True
+                for _ in range(self.prefetch):
+                    w, h = self._pull_one()
+                    wait += w
+                    hit = hit and h
+            dev_batch, meta = self._queue.popleft()
+            sp.tag(batch=meta.seq)
+            w, h = self._pull_one()              # refill behind the consumer
+            self.step_stats.on_wait(wait + w, blocked=not (hit and h))
+            return dev_batch, meta
 
 
 __all__ = ["DeviceFeed", "ImageFeed", "augment_draws", "batch_to_numpy"]

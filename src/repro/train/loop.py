@@ -16,6 +16,7 @@ from typing import Callable, Dict, Optional
 import jax
 
 from repro.core import CassandraLoader, KVStore, LoaderConfig, VirtualClock
+from repro.core.stats import span
 from repro.data.pipeline import DeviceFeed
 from repro.train.checkpoint import CheckpointManager
 from repro.train.optimizer import OptimizerConfig
@@ -96,13 +97,16 @@ def run_training(model, store: KVStore, uuids, loader_cfg: LoaderConfig,
     t0 = None                 # set after the first step: sps excludes the
     #                           jit compile baked into step one
     for step in range(start_step, loop_cfg.total_steps):
-        dev_batch, _meta = next(feed)
+        n = step + 1                 # the step as history numbers it
+        with span("train.next", step=n):
+            dev_batch, _meta = next(feed)
         batch = {"tokens": dev_batch["tokens"],
                  "loss_mask": dev_batch["loss_mask"]}
-        c0 = time.perf_counter()
-        state, metrics = step_fn(state, batch)
-        jax.block_until_ready(metrics["loss"])
-        compute = step_s = time.perf_counter() - c0
+        with span("train.step", step=n):
+            c0 = time.perf_counter()
+            state, metrics = step_fn(state, batch)
+            jax.block_until_ready(metrics["loss"])
+            compute = step_s = time.perf_counter() - c0
         if loop_cfg.charge_step_time is not None:
             compute = loop_cfg.charge_step_time
         if virtual:
@@ -112,18 +116,20 @@ def run_training(model, store: KVStore, uuids, loader_cfg: LoaderConfig,
         ss.on_compute(compute, t_end=clk.now())
         if t0 is None:
             t0 = time.time()
-        if (step + 1) % loop_cfg.log_every == 0 or step == start_step:
-            loss = float(metrics["loss"])
-            rec = {"step": step + 1, "loss": loss, "step_s": step_s,
-                   "sps": (step - start_step) * B
-                   / max(time.time() - t0, 1e-9),
-                   "stall_frac": ss.stall_frac(skip=1),
-                   "goodput_sps": ss.goodput_sps(B, skip=1)}
-            history.append(rec)
-            if on_metrics:
-                on_metrics(rec)
-        if ckpt and (step + 1) % loop_cfg.checkpoint_every == 0:
-            ckpt.save(step + 1, state, extra=ckpt_extra(), blocking=False)
+        if n % loop_cfg.log_every == 0 or step == start_step:
+            with span("train.log", step=n):
+                loss = float(metrics["loss"])
+                rec = {"step": n, "loss": loss, "step_s": step_s,
+                       "sps": (step - start_step) * B
+                       / max(time.time() - t0, 1e-9),
+                       "stall_frac": ss.stall_frac(skip=1),
+                       "goodput_sps": ss.goodput_sps(B, skip=1)}
+                history.append(rec)
+                if on_metrics:
+                    on_metrics(rec)
+        if ckpt and n % loop_cfg.checkpoint_every == 0:
+            with span("train.ckpt", step=n):
+                ckpt.save(n, state, extra=ckpt_extra(), blocking=False)
     if ckpt:
         ckpt.save(loop_cfg.total_steps, state, extra=ckpt_extra(),
                   blocking=True)
