@@ -21,11 +21,28 @@ class ArchConfig:
     qk_norm: bool = False
     rope_theta: float = 1e6
     norm_eps: float = 1e-5
-    tie_embeddings: bool = False
+    tie_embeddings: bool = True    # False: a head of its own unembeds
+    # --- latent attention (MLA; on where kv_lora_rank > 0) ---
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0             # the router's outputs
     top_k: int = 0
     capacity_factor: float = 1.25
+    # "softmax": softmax gate, capacity-bounded layer over all experts;
+    # "sigmoid": DeepSeek-V3's sigmoid gate (noaux_tc, one group), dropless
+    # layer over the experts held here, shared experts beside them
+    router: str = "softmax"
+    d_ff_expert: int = 0           # an expert's width (0: d_ff)
+    n_shared_experts: int = 0
+    first_dense_layers: int = 0    # leading layers with a dense MLP of d_ff
+    routed_scale: float = 1.0
+    balance_coef: float = 0.0      # weight of the sequence-wise balance loss
+    # the chip's share: experts experts_offset .. +experts_here (0: all)
+    experts_here: int = 0
+    experts_offset: int = 0
     # --- SSM / hybrid ---
     ssm_state: int = 0
     window: int = 0                # sliding-window size for SWA attention
@@ -46,6 +63,14 @@ class ArchConfig:
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
 
+    @property
+    def expert_width(self) -> int:
+        return self.d_ff_expert or self.d_ff
+
+    @property
+    def n_experts_here(self) -> int:
+        return self.experts_here or self.n_experts
+
     def scaled(self, **kw) -> "ArchConfig":
         return dataclasses.replace(self, **kw)
 
@@ -63,6 +88,14 @@ class ArchConfig:
             enc_layers=min(self.enc_layers, 2) if self.enc_layers else 0,
             enc_frames=min(self.enc_frames, 24) if self.enc_frames else 0,
             n_patches=min(self.n_patches, 8) if self.n_patches else 0,
+            kv_lora_rank=min(self.kv_lora_rank, 32),
+            qk_nope_dim=min(self.qk_nope_dim, 16),
+            qk_rope_dim=min(self.qk_rope_dim, 8),
+            v_head_dim=min(self.v_head_dim, 16),
+            d_ff_expert=min(self.d_ff_expert, 32),
+            first_dense_layers=min(self.first_dense_layers, 1),
+            experts_here=min(self.experts_here, 4),
+            experts_offset=0,
             dtype="float32", scan_layers=True, remat=False,
         )
 
@@ -89,7 +122,7 @@ SHAPES: Dict[str, ShapeConfig] = {
 ARCH_IDS: List[str] = [
     "qwen3_4b", "yi_34b", "qwen3_14b", "stablelm_1_6b", "whisper_tiny",
     "grok_1_314b", "kimi_k2_1t_a32b", "hymba_1_5b", "xlstm_350m",
-    "internvl2_2b",
+    "internvl2_2b", "moonlight_16b_a3b",
 ]
 
 # long_500k needs sub-quadratic attention: runs only for ssm/hybrid families.

@@ -2,7 +2,7 @@
 d_ff=2048 per expert. [arXiv:2501.kimi2 (paper-table; unverified)]
 
 Fits 512x16GB only with 8-bit optimizer state + full FSDPxTP parameter
-sharding (see train/optimizer.py and EXPERIMENTS.md §Dry-run).
+sharding (see train/optimizer.py).
 """
 
 from .base import ArchConfig

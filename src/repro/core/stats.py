@@ -124,6 +124,7 @@ class StepStats:
         self.step_end_t: List[float] = []  # clock time at each step end
         self.buffer_hits = 0               # __next__ served without blocking
         self.blocked = 0                   # __next__ had to wait on the loader
+        self.counters: Dict[str, List[float]] = {}   # name -> one per step
 
     # -- hooks -------------------------------------------------------------
     def on_wait(self, wait: float, blocked: bool = True) -> None:
@@ -140,6 +141,12 @@ class StepStats:
         if t_end is None:
             t_end = self._clock.now() if self._clock is not None else 0.0
         self.step_end_t.append(float(t_end))
+
+    def on_counter(self, name: str, value: float) -> None:
+        """A counter the step reported (``moe.slots_here``, ...); also
+        counted by the installed recorder, if any."""
+        self.counters.setdefault(name, []).append(float(value))
+        count(name, value)
 
     # -- summaries ---------------------------------------------------------
     @property
@@ -369,6 +376,14 @@ def span(name: str, **ids):
     return rec.span(name, **ids)
 
 
+def count(name: str, amount: float = 1.0) -> None:
+    """Add to a running sum of the installed recorder; nothing while none is
+    installed."""
+    rec = active
+    if rec is not None:
+        rec.count(name, amount)
+
+
 def summarize(values: np.ndarray) -> dict:
     if values.size == 0:
         return {"mean": 0.0, "std": 0.0, "p50": 0.0, "p99": 0.0, "max": 0.0}
@@ -379,4 +394,5 @@ def summarize(values: np.ndarray) -> dict:
 
 
 __all__ = ["LoaderStats", "NULL_SPAN", "Recorder", "Span", "StepStats",
-           "disable", "enable", "span", "summarize", "windowed_series"]
+           "count", "disable", "enable", "span", "summarize",
+           "windowed_series"]

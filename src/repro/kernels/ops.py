@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from .crop_norm import crop_mirror_normalize as _cmn
 from .decode_attention import flash_decode as _flash_decode
 from .flash_attention import flash_attention as _flash_attention
-from .moe_gmm import grouped_matmul as _gmm
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -41,13 +40,4 @@ def crop_mirror_normalize(img, oy, ox, mirror, mean, inv_std, *, out_h, out_w,
                 interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("block_c", "block_f", "block_d",
-                                             "interpret"))
-def grouped_matmul(x, w, *, block_c=128, block_f=128, block_d=512,
-                   interpret: bool = False):
-    return _gmm(x, w, block_c=block_c, block_f=block_f, block_d=block_d,
-                interpret=interpret)
-
-
-__all__ = ["flash_attention", "flash_decode", "crop_mirror_normalize",
-           "grouped_matmul"]
+__all__ = ["flash_attention", "flash_decode", "crop_mirror_normalize"]

@@ -97,12 +97,5 @@ def crop_mirror_normalize_np(img: np.ndarray, oy, ox, mirror,
     return out
 
 
-def gmm_reference(x: jax.Array, w: jax.Array) -> jax.Array:
-    """Grouped (per-expert) matmul: x (E,C,d) @ w (E,d,f) -> (E,C,f)."""
-    return jnp.einsum("ecd,edf->ecf", x.astype(jnp.float32),
-                      w.astype(jnp.float32)).astype(x.dtype)
-
-
 __all__ = ["mha_reference", "decode_reference",
-           "crop_mirror_normalize_reference", "crop_mirror_normalize_np",
-           "gmm_reference"]
+           "crop_mirror_normalize_reference", "crop_mirror_normalize_np"]

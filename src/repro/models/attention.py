@@ -140,12 +140,12 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def _mea_forward(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
                  window: int, q_chunk: int, kv_chunk: int):
-    """Online-softmax forward. q (B,S,H,Dh); k,v (B,T,K,Dh).
+    """Online-softmax forward. q, k (…, Dh); v (B,T,K,Dv).
 
-    Returns (out (B,S,H,Dh), lse (B,K,G,S) f32). Positions are arange.
+    Returns (out (B,S,H,Dv), lse (B,K,G,S) f32). Positions are arange.
     """
     B, S, H, Dh = q.shape
-    T, K = k.shape[1], k.shape[2]
+    T, K, Dv = k.shape[1], k.shape[2], v.shape[3]
     G = H // K
     nq, nk = -(-S // q_chunk), -(-T // kv_chunk)
     scale = Dh ** -0.5
@@ -153,7 +153,7 @@ def _mea_forward(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
     qg = (q.reshape(B, S, K, G, Dh).transpose(0, 2, 3, 1, 4)
           .reshape(B, K, G, nq, q_chunk, Dh))
     kc = k.transpose(0, 2, 1, 3).reshape(B, K, nk, kv_chunk, Dh)
-    vc = v.transpose(0, 2, 1, 3).reshape(B, K, nk, kv_chunk, Dh)
+    vc = v.transpose(0, 2, 1, 3).reshape(B, K, nk, kv_chunk, Dv)
 
     def per_q_chunk(inputs):
         qc, iq = inputs                    # (B,K,G,qc,Dh), ()
@@ -175,7 +175,7 @@ def _mea_forward(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
 
         m0 = jnp.full((B, K, G, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, K, G, q_chunk), jnp.float32)
-        a0 = jnp.zeros((B, K, G, q_chunk, Dh), jnp.float32)
+        a0 = jnp.zeros((B, K, G, q_chunk, Dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
             kv_step, (m0, l0, a0),
             (kc.transpose(2, 0, 1, 3, 4), vc.transpose(2, 0, 1, 3, 4),
@@ -186,9 +186,9 @@ def _mea_forward(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
 
     o, lse = jax.lax.map(per_q_chunk,
                          (qg.transpose(3, 0, 1, 2, 4, 5), jnp.arange(nq)))
-    # o: (nq,B,K,G,qc,Dh) -> (B,S,H,Dh);  lse: (nq,B,K,G,qc) -> (B,K,G,S)
-    o = o.transpose(1, 2, 3, 0, 4, 5).reshape(B, K, G, S, Dh)
-    o = o.transpose(0, 3, 1, 2, 4).reshape(B, S, H, Dh)
+    # o: (nq,B,K,G,qc,Dv) -> (B,S,H,Dv);  lse: (nq,B,K,G,qc) -> (B,K,G,S)
+    o = o.transpose(1, 2, 3, 0, 4, 5).reshape(B, K, G, S, Dv)
+    o = o.transpose(0, 3, 1, 2, 4).reshape(B, S, H, Dv)
     lse = lse.transpose(1, 2, 3, 0, 4).reshape(B, K, G, S)
     return o, lse
 
@@ -213,15 +213,15 @@ def _mea_bwd(causal, window, q_chunk, kv_chunk, res, dout):
     """
     q, k, v, out, lse = res
     B, S, H, Dh = q.shape
-    T, K = k.shape[1], k.shape[2]
+    T, K, Dv = k.shape[1], k.shape[2], v.shape[3]
     G = H // K
     nq, nk = -(-S // q_chunk), -(-T // kv_chunk)
     scale = Dh ** -0.5
 
     qg = (q.reshape(B, S, K, G, Dh).transpose(0, 2, 3, 1, 4)
           .reshape(B, K, G, nq, q_chunk, Dh))
-    do_g = (dout.reshape(B, S, K, G, Dh).transpose(0, 2, 3, 1, 4)
-            .reshape(B, K, G, nq, q_chunk, Dh))
+    do_g = (dout.reshape(B, S, K, G, Dv).transpose(0, 2, 3, 1, 4)
+            .reshape(B, K, G, nq, q_chunk, Dv))
     lse_c = lse.reshape(B, K, G, nq, q_chunk)
     # delta_i = sum_d dO_i * O_i
     delta = jnp.einsum("bshd,bshd->bsh", dout.astype(jnp.float32),
@@ -229,7 +229,7 @@ def _mea_bwd(causal, window, q_chunk, kv_chunk, res, dout):
     delta_c = (delta.reshape(B, S, K, G).transpose(0, 2, 3, 1)
                .reshape(B, K, G, nq, q_chunk))
     kc = k.transpose(0, 2, 1, 3).reshape(B, K, nk, kv_chunk, Dh)
-    vc = v.transpose(0, 2, 1, 3).reshape(B, K, nk, kv_chunk, Dh)
+    vc = v.transpose(0, 2, 1, 3).reshape(B, K, nk, kv_chunk, Dv)
 
     def kv_step(dq_acc, inputs):
         kb, vb, j = inputs                 # (B,K,kvc,Dh) x2, ()
@@ -272,7 +272,7 @@ def _mea_bwd(causal, window, q_chunk, kv_chunk, res, dout):
     # dk_c/dv_c: (nk, B, K, kvc, Dh) -> (B, K, T, Dh) -> (B, T, K, Dh)
     dk = (dk_c.transpose(1, 2, 0, 3, 4).reshape(B, K, T, Dh)
           .transpose(0, 2, 1, 3).astype(k.dtype))
-    dv = (dv_c.transpose(1, 2, 0, 3, 4).reshape(B, K, T, Dh)
+    dv = (dv_c.transpose(1, 2, 0, 3, 4).reshape(B, K, T, Dv)
           .transpose(0, 2, 1, 3).astype(v.dtype))
     return dq, dk, dv
 
@@ -284,7 +284,8 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       q_pos: jax.Array = None, kv_pos: jax.Array = None, *,
                       causal: bool = True, window: int = 0,
                       q_chunk: int = 1024, kv_chunk: int = 1024) -> jax.Array:
-    """Memory-efficient attention with a flash-style custom VJP.
+    """Memory-efficient attention with a flash-style custom VJP; v may be
+    narrower than q and k (the scale follows q's width).
 
     Positions are implicit arange (the q_pos/kv_pos arguments are accepted
     for API parity with dense_attention but must be arange if given).

@@ -1,4 +1,7 @@
-"""Mixture-of-Experts with sort-based, capacity-bounded dispatch.
+"""Mixture-of-Experts layers: a capacity-bounded one over all experts, and a
+dropless one over the experts a chip holds.
+
+Capacity-bounded (softmax gate):
 
 GShard-style one-hot dispatch einsums cost O(T^2 k cf d) — quadratic in
 tokens — so we use the sort/scatter formulation (as MaxText's dropping MoE
@@ -10,6 +13,15 @@ expert compute is E*C*d*f*3 matmuls with E*C = k*cf*T.
 Sharding: tokens are batch-sharded ("data"), experts are sharded over
 "model" when divisible (else the FFN dim is); XLA inserts the all-to-alls at
 the scatter/gather boundaries.
+
+Dropless (DeepSeek-V3's sigmoid gate, ``dropless_apply``): the layer is
+told which experts it holds, ``offset .. offset + E_here``, routes every
+token over all experts, sorts the (token, slot) pairs routed to its own by
+expert and runs one grouped matmul over the uneven groups
+(``jax.lax.ragged_dot``) for gate, up and down, so it drops nothing.
+Slots routed to experts held elsewhere are left out: on one chip the
+layer's part of the result is what its experts give, plus the shared
+experts, which every chip computes alike.
 """
 
 from __future__ import annotations
@@ -19,6 +31,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from .layers import swiglu, swiglu_spec
 from .params import P
 
 
@@ -40,7 +53,7 @@ def moe_apply(params: Dict, x: jax.Array, *, top_k: int,
     Dispatch is *grouped per batch row* (per sequence): every sort/scatter/
     gather batches over B, so all dispatch buffers shard over the data axis
     — a single global token sort would force multi-hundred-GB replicated
-    (B*S*k, d) tensors under SPMD (measured; see EXPERIMENTS.md §Perf).
+    (B*S*k, d) tensors under SPMD.
     Capacity is per-group, C = ceil(Sc*k*cf/E), the standard per-device
     capacity of GShard-family implementations.
 
@@ -138,4 +151,80 @@ def _moe_chunk(params: Dict, x: jax.Array, *, top_k: int,
     return out, metrics
 
 
-__all__ = ["moe_spec", "moe_apply"]
+def dropless_spec(d: int, f: int, n_experts: int, n_here: int,
+                  n_shared: int) -> Dict:
+    """Router over all ``n_experts``; the ``n_here`` held experts' weights;
+    the shared experts as one SwiGLU of width ``n_shared * f``."""
+    return {
+        "router": P((d, n_experts), ("d_model", "experts")),
+        "w_gate": P((n_here, d, f), ("experts", "d_model", "d_ff")),
+        "w_up": P((n_here, d, f), ("experts", "d_model", "d_ff")),
+        "w_down": P((n_here, f, d), ("experts", "d_ff", "d_model")),
+        "shared": swiglu_spec(d, n_shared * f),
+    }
+
+
+def sigmoid_route(x: jax.Array, router: jax.Array, top_k: int,
+                  scale: float) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """DeepSeek-V3's gate with one group and a zero correction bias:
+    ``s = sigmoid(x Wr)`` in float32, the top ``k`` of ``s``, weights
+    ``s_sel / sum(s_sel) * scale``.  Returns (s, expert ids, weights)."""
+    s = jax.nn.sigmoid(jnp.einsum("...d,de->...e", x.astype(jnp.float32),
+                                  router.astype(jnp.float32)))
+    w, idx = jax.lax.top_k(s, top_k)
+    return s, idx, w / jnp.sum(w, -1, keepdims=True) * scale
+
+
+def sequence_balance_loss(s: jax.Array, idx: jax.Array) -> jax.Array:
+    """DeepSeek-V3's sequence-wise balance loss over all experts, averaged
+    over sequences: ``sum_i f_i P_i`` with ``f_i = E/(k S) * #{t: i in
+    top-k(t)}`` and ``P_i`` the mean over the sequence of ``s_i`` normed
+    over the experts.  s (B,S,E), idx (B,S,k)."""
+    B, S, E = s.shape
+    k = idx.shape[-1]
+    hits = jnp.zeros((B, E), jnp.float32).at[
+        jnp.arange(B)[:, None], idx.reshape(B, S * k)].add(1.0)
+    f = hits * (E / (k * S))
+    p = jnp.mean(s / jnp.sum(s, -1, keepdims=True), axis=1)
+    return jnp.mean(jnp.sum(f * p, -1))
+
+
+def dropless_apply(params: Dict, x: jax.Array, *, top_k: int, offset: int,
+                   scale: float) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """x (B,S,d) -> (B,S,d) and the layer's counters: the balance loss, the
+    slots routed to the held experts and the largest held expert's slots."""
+    B, S, d = x.shape
+    T, k = B * S, top_k
+    E_here = params["w_gate"].shape[0]
+    with jax.named_scope("moe.dispatch"):
+        s, idx, w = sigmoid_route(x, params["router"], k, scale)
+        local = idx.reshape(T * k) - offset
+        held = (local >= 0) & (local < E_here)
+        # slots of other chips' experts sort last, past every group
+        order = jnp.argsort(jnp.where(held, local, E_here), stable=True)
+        sizes = jnp.zeros((E_here,), jnp.int32).at[local].add(
+            held.astype(jnp.int32), mode="drop")
+        # the chip's kernel leaves rows past the last group unwritten, in
+        # its outputs and in the gradients it gives: select them out here
+        # and in the combine, so that nothing unwritten reaches x or out
+        in_group = jnp.arange(T * k) < jnp.sum(sizes)
+        xs = jnp.where(in_group[:, None], x.reshape(T, d)[order // k], 0)
+    with jax.named_scope("moe.gmm"):
+        g = jax.lax.ragged_dot(xs, params["w_gate"], sizes)
+        u = jax.lax.ragged_dot(xs, params["w_up"], sizes)
+        y = jax.lax.ragged_dot(jax.nn.silu(g) * u, params["w_down"], sizes)
+    with jax.named_scope("moe.combine"):
+        slot_row = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32))
+        ys = jnp.where(held[:, None], y[slot_row], 0).reshape(T, k, d)
+        wt = jnp.where(held.reshape(T, k), w.reshape(T, k), 0.0).astype(x.dtype)
+        out = jnp.einsum("tkd,tk->td", ys, wt).reshape(B, S, d)
+        out = out + swiglu(params["shared"], x)
+    aux = {"moe_balance_loss": sequence_balance_loss(s, idx),
+           "moe.slots_here": jnp.sum(sizes).astype(jnp.float32),
+           "moe.load_max": jnp.max(sizes).astype(jnp.float32)}
+    return out, aux
+
+
+__all__ = ["moe_spec", "moe_apply", "dropless_spec", "dropless_apply",
+           "sigmoid_route", "sequence_balance_loss"]

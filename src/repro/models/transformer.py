@@ -3,7 +3,10 @@
 One scanned pre-norm block: x += attn(norm(x)); x += ffn|moe(norm(x)).
 Layers are stacked along a leading "layers" axis and executed with
 ``jax.lax.scan`` (+ optional remat) so the HLO stays depth-independent —
-required to compile the 61-layer/1T-param configs in the dry-run.
+required to compile the 61-layer/1T-param configs in the dry-run.  A
+DeepSeek-style model runs its leading dense layers ("dense_blocks") as a
+stack of their own before the expert layers ("blocks"); its attention is
+latent (``models/mla.py``) where ``kv_lora_rank`` is set.
 """
 
 from __future__ import annotations
@@ -16,13 +19,20 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig, ShapeConfig
 
 from . import attention as attn
+from . import mla
 from . import moe as moe_mod
-from .layers import (embed, embed_spec, rmsnorm, rmsnorm_spec, softmax_xent,
-                     swiglu, swiglu_spec, unembed)
+from .layers import (embed, embed_spec, output_head, output_head_spec,
+                     rmsnorm, rmsnorm_spec, softmax_xent, swiglu, swiglu_spec,
+                     unembed)
 from .params import (P, abstract_params, init_params, logical_axes,
                      stack_layer_specs)
 
 DENSE_ATTN_MAX_SEQ = 2048   # above this, use chunked (memory-efficient) attn
+
+# How each layer's MoE counter is reduced over the layers.
+_AUX_REDUCE = {"moe_aux_loss": jnp.mean, "moe_z_loss": jnp.mean,
+               "moe_dropped_frac": jnp.mean, "moe_balance_loss": jnp.sum,
+               "moe.slots_here": jnp.sum, "moe.load_max": jnp.max}
 
 
 class DecoderLM:
@@ -32,6 +42,10 @@ class DecoderLM:
         self.cfg = cfg
         self.is_moe = cfg.n_experts > 0
         self.is_vlm = cfg.n_patches > 0
+        self.is_mla = cfg.kv_lora_rank > 0
+        self.dropless = cfg.router == "sigmoid"
+        if cfg.router not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router {cfg.router!r}")
         self.dtype = jnp.dtype(cfg.dtype)
         # optional sharding constrainers (set by launchers)
         self.constrain_act = None
@@ -40,15 +54,23 @@ class DecoderLM:
         self.constrain_moe = None
 
     # -- specs ---------------------------------------------------------------
-    def block_spec(self) -> Dict:
+    def block_spec(self, moe: Optional[bool] = None) -> Dict:
         c = self.cfg
-        spec = {
-            "ln1": rmsnorm_spec(c.d_model),
-            "attn": attn.gqa_spec(c.d_model, c.n_heads, c.n_kv_heads,
-                                  c.resolved_head_dim, qk_norm=c.qk_norm),
-            "ln2": rmsnorm_spec(c.d_model),
-        }
-        if self.is_moe:
+        moe = self.is_moe if moe is None else moe
+        if self.is_mla:
+            attn_spec = mla.mla_spec(c.d_model, c.n_heads, c.kv_lora_rank,
+                                     c.qk_nope_dim, c.qk_rope_dim,
+                                     c.v_head_dim)
+        else:
+            attn_spec = attn.gqa_spec(c.d_model, c.n_heads, c.n_kv_heads,
+                                      c.resolved_head_dim, qk_norm=c.qk_norm)
+        spec = {"ln1": rmsnorm_spec(c.d_model), "attn": attn_spec,
+                "ln2": rmsnorm_spec(c.d_model)}
+        if moe and self.dropless:
+            spec["moe"] = moe_mod.dropless_spec(
+                c.d_model, c.expert_width, c.n_experts, c.n_experts_here,
+                c.n_shared_experts)
+        elif moe:
             spec["moe"] = moe_mod.moe_spec(c.d_model, c.d_ff, c.n_experts)
         else:
             spec["mlp"] = swiglu_spec(c.d_model, c.d_ff)
@@ -56,11 +78,18 @@ class DecoderLM:
 
     def param_specs(self) -> Dict:
         c = self.cfg
+        n_dense = c.first_dense_layers
         spec = {
             "embed": embed_spec(c.vocab, c.d_model),
-            "blocks": stack_layer_specs(self.block_spec(), c.n_layers),
+            "blocks": stack_layer_specs(self.block_spec(),
+                                        c.n_layers - n_dense),
             "ln_f": rmsnorm_spec(c.d_model),
         }
+        if n_dense:
+            spec["dense_blocks"] = stack_layer_specs(
+                self.block_spec(moe=False), n_dense)
+        if not c.tie_embeddings:
+            spec["head"] = output_head_spec(c.d_model, c.vocab)
         if self.is_vlm:
             spec["mm_proj"] = {"w": P((c.d_model, c.d_model),
                                       ("d_model", "d_model_out"))}
@@ -76,11 +105,14 @@ class DecoderLM:
         return logical_axes(self.param_specs())
 
     # -- forward ---------------------------------------------------------------
-    def _block(self, layer_params: Dict, x: jax.Array, positions: jax.Array
-               ) -> Tuple[jax.Array, Dict]:
+    def _attention(self, p: Dict, h: jax.Array, positions: jax.Array
+                   ) -> jax.Array:
         c = self.cfg
-        h = rmsnorm(layer_params["ln1"], x, c.norm_eps)
-        q, k, v = attn.project_qkv(layer_params["attn"], h)
+        if self.is_mla:
+            return mla.mla_attention(p, h, positions, nope=c.qk_nope_dim,
+                                     rope=c.qk_rope_dim, theta=c.rope_theta,
+                                     dense_max_seq=DENSE_ATTN_MAX_SEQ)
+        q, k, v = attn.project_qkv(p, h)
         q = attn.apply_rope(q, positions, c.rope_theta)
         k = attn.apply_rope(k, positions, c.rope_theta)
         k = attn.expand_kv(k, c.n_heads)     # TP-friendly GQA
@@ -89,58 +121,74 @@ class DecoderLM:
             q = self.constrain_q(q)
             k = self.constrain_kv(k)
             v = self.constrain_kv(v)
-        S = x.shape[1]
+        S = h.shape[1]
         if S <= DENSE_ATTN_MAX_SEQ:
             o = attn.dense_attention(q, k, v, positions[0], positions[0],
                                      causal=True, window=c.window)
         else:
             o = attn.chunked_attention(q, k, v, positions[0], positions[0],
                                        causal=True, window=c.window)
-        x = x + attn.project_out(layer_params["attn"], o)
+        return attn.project_out(p, o)
+
+    def _ffn(self, layer_params: Dict, h: jax.Array, constrain=None
+             ) -> Tuple[jax.Array, Dict]:
+        c = self.cfg
+        if "mlp" in layer_params:
+            return swiglu(layer_params["mlp"], h), {}
+        if self.dropless:
+            return moe_mod.dropless_apply(
+                layer_params["moe"], h, top_k=c.top_k,
+                offset=c.experts_offset, scale=c.routed_scale)
+        return moe_mod.moe_apply(layer_params["moe"], h, top_k=c.top_k,
+                                 capacity_factor=c.capacity_factor,
+                                 constrain=constrain)
+
+    def _block(self, layer_params: Dict, x: jax.Array, positions: jax.Array
+               ) -> Tuple[jax.Array, Dict]:
+        c = self.cfg
+        h = rmsnorm(layer_params["ln1"], x, c.norm_eps)
+        x = x + self._attention(layer_params["attn"], h, positions)
         h = rmsnorm(layer_params["ln2"], x, c.norm_eps)
-        aux = {}
-        if self.is_moe:
-            y, aux = moe_mod.moe_apply(layer_params["moe"], h,
-                                       top_k=c.top_k,
-                                       capacity_factor=c.capacity_factor,
-                                       constrain=self.constrain_moe)
-        else:
-            y = swiglu(layer_params["mlp"], h)
+        y, aux = self._ffn(layer_params, h, self.constrain_moe)
         return x + y, aux
 
-    def _run_blocks(self, params: Dict, x: jax.Array, positions: jax.Array
-                    ) -> Tuple[jax.Array, Dict]:
+    def _run_stack(self, blocks: Dict, x: jax.Array, positions: jax.Array
+                   ) -> Tuple[jax.Array, Dict]:
+        """One stack of layers; each layer's counters, stacked."""
         c = self.cfg
         cst = self.constrain_act or (lambda t: t)
-        x = cst(x)
 
-        def body(carry, layer_params):
-            h, aux_acc = carry
+        def body(h, layer_params):
             h, aux = self._block(layer_params, h, positions)
-            h = cst(h)
-            if aux:
-                aux_acc = jax.tree.map(lambda a, b: a + b, aux_acc,
-                                       {k: jnp.asarray(v, jnp.float32)
-                                        for k, v in aux.items()})
-            return (h, aux_acc), None
+            return cst(h), {k: jnp.asarray(v, jnp.float32)
+                            for k, v in aux.items()}
 
-        aux0 = ({"moe_aux_loss": jnp.zeros((), jnp.float32),
-                 "moe_z_loss": jnp.zeros((), jnp.float32),
-                 "moe_dropped_frac": jnp.zeros((), jnp.float32)}
-                if self.is_moe else {})
         fn = body
         if c.remat:
             fn = jax.checkpoint(body,
                                 policy=jax.checkpoint_policies.nothing_saveable)
         if c.scan_layers:
-            (x, aux), _ = jax.lax.scan(fn, (x, aux0), params["blocks"])
-        else:
-            for i in range(c.n_layers):
-                layer = jax.tree.map(lambda p: p[i], params["blocks"])
-                (x, aux), _ = fn((x, aux0), layer)
-        if aux:
-            aux = {k: v / c.n_layers for k, v in aux.items()}
-        return x, aux
+            return jax.lax.scan(fn, x, blocks)
+        n = jax.tree.leaves(blocks)[0].shape[0]
+        auxs = []
+        for i in range(n):
+            x, aux = fn(x, jax.tree.map(lambda p: p[i], blocks))
+            auxs.append(aux)
+        return x, jax.tree.map(lambda *a: jnp.stack(a), *auxs)
+
+    def _run_blocks(self, params: Dict, x: jax.Array, positions: jax.Array
+                    ) -> Tuple[jax.Array, Dict]:
+        cst = self.constrain_act or (lambda t: t)
+        x = cst(x)
+        if "dense_blocks" in params:
+            x, _ = self._run_stack(params["dense_blocks"], x, positions)
+        x, aux = self._run_stack(params["blocks"], x, positions)
+        return x, {k: _AUX_REDUCE[k](v) for k, v in aux.items()}
+
+    def _logits(self, params: Dict, x: jax.Array) -> jax.Array:
+        if "head" in params:
+            return output_head(params["head"], x)
+        return unembed(params["embed"], x)
 
     def forward(self, params: Dict, tokens: jax.Array,
                 extras: Optional[Dict] = None) -> Tuple[jax.Array, Dict]:
@@ -156,8 +204,7 @@ class DecoderLM:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         x, aux = self._run_blocks(params, x, positions)
         x = rmsnorm(params["ln_f"], x, c.norm_eps)
-        logits = unembed(params["embed"], x)
-        return logits, aux
+        return self._logits(params, x), aux
 
     # -- losses ---------------------------------------------------------------
     def train_loss(self, params: Dict, batch: Dict
@@ -173,7 +220,10 @@ class DecoderLM:
             mask = (pos >= self.cfg.n_patches).astype(jnp.float32)
         loss = softmax_xent(logits[:, :-1], targets, mask)
         metrics = {"xent": loss}
-        if self.is_moe:
+        if self.dropless:
+            loss = loss + self.cfg.balance_coef * aux["moe_balance_loss"]
+            metrics.update(aux)
+        elif self.is_moe:
             loss = loss + 0.01 * aux["moe_aux_loss"] + 1e-3 * aux["moe_z_loss"]
             metrics.update(aux)
         metrics["loss"] = loss
@@ -185,19 +235,18 @@ class DecoderLM:
         return min(c.window, seq_len) if c.window else seq_len
 
     def init_cache(self, batch: int, seq_len: int) -> Dict:
-        c = self.cfg
-        T = self._cache_len(seq_len)
-        one = lambda: attn.init_kv_cache(batch, T, c.n_kv_heads,
-                                         c.resolved_head_dim, self.dtype)
-        stacked = jax.tree.map(
-            lambda *xs: jnp.stack(xs), *[one() for _ in range(c.n_layers)])
-        return stacked
+        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                            self.cache_specs(batch, seq_len))
 
     def cache_specs(self, batch: int, seq_len: int) -> Dict:
         c = self.cfg
         T = self._cache_len(seq_len)
-        spec = attn.cache_specs(batch, T, c.n_kv_heads, c.resolved_head_dim,
-                                self.dtype)
+        if self.is_mla:
+            spec = mla.mla_cache_specs(batch, T, c.kv_lora_rank,
+                                       c.qk_rope_dim, self.dtype)
+        else:
+            spec = attn.cache_specs(batch, T, c.n_kv_heads,
+                                    c.resolved_head_dim, self.dtype)
         return jax.tree.map(
             lambda s: jax.ShapeDtypeStruct((c.n_layers,) + s.shape, s.dtype),
             spec)
@@ -211,23 +260,33 @@ class DecoderLM:
         def body(x, scanned):
             layer_params, layer_cache = scanned
             h = rmsnorm(layer_params["ln1"], x, c.norm_eps)
-            o, new_cache = attn.decode_attention(
-                layer_params["attn"], layer_cache, h, window=c.window,
-                rope_theta=c.rope_theta)
+            if self.is_mla:
+                o, new_cache = mla.mla_decode(
+                    layer_params["attn"], layer_cache, h, nope=c.qk_nope_dim,
+                    rope=c.qk_rope_dim, theta=c.rope_theta)
+            else:
+                o, new_cache = attn.decode_attention(
+                    layer_params["attn"], layer_cache, h, window=c.window,
+                    rope_theta=c.rope_theta)
             x = x + o
             h = rmsnorm(layer_params["ln2"], x, c.norm_eps)
-            if self.is_moe:
-                y, _ = moe_mod.moe_apply(layer_params["moe"], h,
-                                         top_k=c.top_k,
-                                         capacity_factor=c.capacity_factor)
-            else:
-                y = swiglu(layer_params["mlp"], h)
+            y, _ = self._ffn(layer_params, h)
             return x + y, new_cache
 
-        x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
+        n_dense = c.first_dense_layers
+        caches = []
+        if n_dense:
+            x, dense_cache = jax.lax.scan(
+                body, x, (params["dense_blocks"],
+                          jax.tree.map(lambda a: a[:n_dense], cache)))
+            caches.append(dense_cache)
+        x, moe_cache = jax.lax.scan(
+            body, x, (params["blocks"],
+                      jax.tree.map(lambda a: a[n_dense:], cache)))
+        caches.append(moe_cache)
+        new_cache = jax.tree.map(lambda *a: jnp.concatenate(a), *caches)
         x = rmsnorm(params["ln_f"], x, c.norm_eps)
-        logits = unembed(params["embed"], x)
-        return logits, new_cache
+        return self._logits(params, x), new_cache
 
     # -- shape plumbing ----------------------------------------------------------
     def input_specs(self, shape: ShapeConfig) -> Dict:
@@ -256,6 +315,8 @@ class DecoderLM:
         return batch
 
     def input_logical_axes(self, shape: ShapeConfig) -> Dict:
+        if shape.kind == "decode" and self.is_mla:
+            return {"tokens": ("batch", None), "cache": mla.MLA_CACHE_AXES}
         kv_cache_axes = {"k": ("layers", "batch", "kv_seq", "kv_heads",
                                "head_dim"),
                          "v": ("layers", "batch", "kv_seq", "kv_heads",

@@ -22,6 +22,9 @@ from repro.train.checkpoint import CheckpointManager
 from repro.train.optimizer import OptimizerConfig
 from repro.train.step import init_state, make_train_step
 
+# Counters a step may report among its metrics, kept per step in StepStats.
+STEP_COUNTERS = ("moe.slots_here", "moe.load_max")
+
 
 @dataclasses.dataclass
 class TrainLoopConfig:
@@ -107,6 +110,9 @@ def run_training(model, store: KVStore, uuids, loader_cfg: LoaderConfig,
             state, metrics = step_fn(state, batch)
             jax.block_until_ready(metrics["loss"])
             compute = step_s = time.perf_counter() - c0
+        reported = {k: metrics[k] for k in STEP_COUNTERS if k in metrics}
+        for name, value in jax.device_get(reported).items():
+            ss.on_counter(name, value)
         if loop_cfg.charge_step_time is not None:
             compute = loop_cfg.charge_step_time
         if virtual:

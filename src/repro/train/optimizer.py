@@ -16,6 +16,10 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+# Stacked leaves larger than this are updated a slice of the leading axis at
+# a time, so the float32 temporaries are one layer's, not the stack's.
+CHUNK_ELEMENTS = 1 << 27
+
 
 @dataclasses.dataclass(frozen=True)
 class OptimizerConfig:
@@ -197,7 +201,7 @@ def adamw_update(grads: Any, opt_state: Dict, params: Any,
         # f32 dequant/update temporaries are per-layer, not per-stack — for
         # kimi's (61,384,7168,2048) expert weights that is the difference
         # between ~5 GB and ~0.1 GB of optimizer temp per buffer.
-        if p.ndim >= 3 and p.size > (1 << 27):
+        if p.ndim >= 3 and p.size > CHUNK_ELEMENTS:
             return jax.lax.map(lambda t: upd(*t), (p, g, m, v))
         return upd(p, g, m, v)
 

@@ -130,25 +130,6 @@ def test_crop_mirror_normalize_clamps_offsets():
     np.testing.assert_allclose(want, clamped, rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("E,C,d,f,bc,bf,bd", [
-    (4, 64, 96, 64, 32, 32, 32),
-    (2, 100, 64, 48, 64, 16, 64),    # padded C/f
-    (8, 32, 128, 128, 32, 128, 128),
-])
-def test_grouped_matmul_sweep(dtype, E, C, d, f, bc, bf, bd):
-    ks = jax.random.split(jax.random.PRNGKey(4), 2)
-    x = jax.random.normal(ks[0], (E, C, d), dtype)
-    w = jax.random.normal(ks[1], (E, d, f), dtype)
-    got = ops.grouped_matmul(x, w, block_c=bc, block_f=bf, block_d=bd,
-                             interpret=True)
-    want = ref.gmm_reference(x, w)
-    tol = dict(rtol=5e-2, atol=5e-1) if dtype == jnp.bfloat16 \
-        else dict(rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), **tol)
-
-
 def test_flash_attention_matches_model_chunked_path():
     """Kernel and the XLA chunked path implement the same math."""
     from repro.models.attention import chunked_attention

@@ -80,3 +80,26 @@ def test_stablelm_train_step_fits_one_chip(one_chip):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes > 0            # the state is donated
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * GiB
+
+
+def test_dropless_expert_layer_compiles_at_moonlight_width(one_chip):
+    """Moonlight's expert layer at its published widths (d 2,048, experts
+    of 1,408, 8 of 64 held, 6 a token, 2 shared) over 4,096 tokens, forward
+    and backward: the grouped matmuls lower to the chip's ragged-dot kernel
+    (a Mosaic custom call), not to a dense expansion."""
+    from repro.models.moe import dropless_apply, dropless_spec
+    from repro.models.params import abstract_params
+
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        abstract_params(dropless_spec(2048, 1408, 64, 8, 2), jnp.bfloat16))
+    x = jax.ShapeDtypeStruct((1, 4096, 2048), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(p, x):
+        y, aux = dropless_apply(p, x, top_k=6, offset=0, scale=2.446)
+        return jnp.sum(y.astype(jnp.float32)) + aux["moe_balance_loss"]
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    assert "tpu_custom_call" in text and "ragged-dot" in text
