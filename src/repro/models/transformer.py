@@ -29,10 +29,11 @@ from .params import (P, abstract_params, init_params, logical_axes,
 
 DENSE_ATTN_MAX_SEQ = 2048   # above this, use chunked (memory-efficient) attn
 
-# How each layer's MoE counter is reduced over the layers.
+# How each layer's counter is reduced over the layers.
 _AUX_REDUCE = {"moe_aux_loss": jnp.mean, "moe_z_loss": jnp.mean,
                "moe_dropped_frac": jnp.mean, "moe_balance_loss": jnp.sum,
-               "moe.slots_here": jnp.sum, "moe.load_max": jnp.max}
+               "moe.slots_here": jnp.sum, "moe.load_max": jnp.max,
+               "attn.blocks_computed": jnp.sum}
 
 
 class DecoderLM:
@@ -106,12 +107,19 @@ class DecoderLM:
 
     # -- forward ---------------------------------------------------------------
     def _attention(self, p: Dict, h: jax.Array, positions: jax.Array
-                   ) -> jax.Array:
+                   ) -> Tuple[jax.Array, Dict]:
+        """The sublayer's output and, where it runs chunked, the blocks
+        that ``chunked_attention`` computes (``attn.blocks_computed``)."""
         c = self.cfg
+        S = h.shape[1]
+        window = 0 if self.is_mla else c.window
+        aux = {} if S <= DENSE_ATTN_MAX_SEQ else {
+            "attn.blocks_computed": attn.attention_blocks(
+                S, S, causal=True, window=window)[0]}
         if self.is_mla:
             return mla.mla_attention(p, h, positions, nope=c.qk_nope_dim,
                                      rope=c.qk_rope_dim, theta=c.rope_theta,
-                                     dense_max_seq=DENSE_ATTN_MAX_SEQ)
+                                     dense_max_seq=DENSE_ATTN_MAX_SEQ), aux
         q, k, v = attn.project_qkv(p, h)
         q = attn.apply_rope(q, positions, c.rope_theta)
         k = attn.apply_rope(k, positions, c.rope_theta)
@@ -121,14 +129,13 @@ class DecoderLM:
             q = self.constrain_q(q)
             k = self.constrain_kv(k)
             v = self.constrain_kv(v)
-        S = h.shape[1]
         if S <= DENSE_ATTN_MAX_SEQ:
             o = attn.dense_attention(q, k, v, positions[0], positions[0],
-                                     causal=True, window=c.window)
+                                     causal=True, window=window)
         else:
             o = attn.chunked_attention(q, k, v, positions[0], positions[0],
-                                       causal=True, window=c.window)
-        return attn.project_out(p, o)
+                                       causal=True, window=window)
+        return attn.project_out(p, o), aux
 
     def _ffn(self, layer_params: Dict, h: jax.Array, constrain=None
              ) -> Tuple[jax.Array, Dict]:
@@ -147,10 +154,11 @@ class DecoderLM:
                ) -> Tuple[jax.Array, Dict]:
         c = self.cfg
         h = rmsnorm(layer_params["ln1"], x, c.norm_eps)
-        x = x + self._attention(layer_params["attn"], h, positions)
+        o, aux = self._attention(layer_params["attn"], h, positions)
+        x = x + o
         h = rmsnorm(layer_params["ln2"], x, c.norm_eps)
-        y, aux = self._ffn(layer_params, h, self.constrain_moe)
-        return x + y, aux
+        y, ffn_aux = self._ffn(layer_params, h, self.constrain_moe)
+        return x + y, {**aux, **ffn_aux}
 
     def _run_stack(self, blocks: Dict, x: jax.Array, positions: jax.Array
                    ) -> Tuple[jax.Array, Dict]:
@@ -180,10 +188,16 @@ class DecoderLM:
                     ) -> Tuple[jax.Array, Dict]:
         cst = self.constrain_act or (lambda t: t)
         x = cst(x)
+        stacks = []
         if "dense_blocks" in params:
-            x, _ = self._run_stack(params["dense_blocks"], x, positions)
+            x, aux = self._run_stack(params["dense_blocks"], x, positions)
+            stacks.append(aux)
         x, aux = self._run_stack(params["blocks"], x, positions)
-        return x, {k: _AUX_REDUCE[k](v) for k, v in aux.items()}
+        stacks.append(aux)
+        # each counter over the layers of every stack that reports it
+        per_layer = {k: jnp.concatenate([a[k] for a in stacks if k in a])
+                     for k in dict.fromkeys(k for a in stacks for k in a)}
+        return x, {k: _AUX_REDUCE[k](v) for k, v in per_layer.items()}
 
     def _logits(self, params: Dict, x: jax.Array) -> jax.Array:
         if "head" in params:
@@ -219,13 +233,11 @@ class DecoderLM:
             pos = jnp.arange(targets.shape[1])[None, :]
             mask = (pos >= self.cfg.n_patches).astype(jnp.float32)
         loss = softmax_xent(logits[:, :-1], targets, mask)
-        metrics = {"xent": loss}
+        metrics = {"xent": loss, **aux}
         if self.dropless:
             loss = loss + self.cfg.balance_coef * aux["moe_balance_loss"]
-            metrics.update(aux)
         elif self.is_moe:
             loss = loss + 0.01 * aux["moe_aux_loss"] + 1e-3 * aux["moe_z_loss"]
-            metrics.update(aux)
         metrics["loss"] = loss
         return loss, metrics
 

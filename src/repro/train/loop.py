@@ -23,7 +23,7 @@ from repro.train.optimizer import OptimizerConfig
 from repro.train.step import init_state, make_train_step
 
 # Counters a step may report among its metrics, kept per step in StepStats.
-STEP_COUNTERS = ("moe.slots_here", "moe.load_max")
+STEP_COUNTERS = ("moe.slots_here", "moe.load_max", "attn.blocks_computed")
 
 
 @dataclasses.dataclass

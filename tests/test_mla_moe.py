@@ -212,6 +212,31 @@ def test_latent_cache_decode_matches_the_full_forward():
         assert rel(step[:, 0], logits[:, t]) < 1e-4, t
 
 
+def test_chunked_attention_blocks_reach_the_step_metrics():
+    """Above ``DENSE_ATTN_MAX_SEQ`` each layer of both stacks runs chunked
+    attention, and the step reports the blocks it computes, summed over the
+    layers, among the counters ``run_training`` keeps."""
+    from repro.models.attention import attention_blocks
+    from repro.models.transformer import DENSE_ATTN_MAX_SEQ
+    from repro.train.loop import STEP_COUNTERS
+
+    model = program()
+    params = ref.init_weights(SEED, SHAPE, jnp.float32)
+    long = 2 * DENSE_ATTN_MAX_SEQ            # a 4 x 4 grid of 1,024 chunks
+    tokens = np.random.default_rng(SEED).integers(
+        0, SHAPE.vocab, size=(1, long)).astype(np.int32)
+    _, metrics = jax.jit(model.train_loss)(
+        params, {"tokens": jnp.asarray(tokens),
+                 "loss_mask": jnp.ones((1, long), jnp.float32)})
+    assert attention_blocks(long, long) == (10, 16)
+    assert float(metrics["attn.blocks_computed"]) == SHAPE.n_layers * 10
+    assert "attn.blocks_computed" in STEP_COUNTERS
+    tokens, mask = batch()
+    _, short = model.train_loss(params, {"tokens": jnp.asarray(tokens),
+                                         "loss_mask": jnp.asarray(mask)})
+    assert "attn.blocks_computed" not in short
+
+
 def test_step_counters_are_kept_per_step_and_counted():
     from repro.core import stats
 
