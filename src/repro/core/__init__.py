@@ -12,7 +12,7 @@ from .flowctl import (FlowControlConfig, FlowController,
                       FlowControllerGroup, SharedIngressLimiter,
                       merge_snapshots)
 from .kvstore import DataRow, KVStore, MetaRow, make_uuid, token_of
-from .loader import CassandraLoader, LoaderConfig, consume_with_step_time, tight_loop
+from .loader import CassandraLoader, LoaderConfig, tight_loop
 from .multihost import MultiHostConfig, MultiHostRun
 from .netsim import (BACKENDS, CASSANDRA, SCYLLA, TIERS, Clock, EventHandle,
                      RealClock, RouteProfile, RouteSchedule, VirtualClock,
@@ -44,7 +44,7 @@ __all__ = [
     "DataRow", "KVStore", "MetaRow",
     "make_uuid", "token_of", "CassandraLoader", "LoaderConfig",
     "MultiHostConfig", "MultiHostRun",
-    "consume_with_step_time", "tight_loop", "BACKENDS", "CASSANDRA", "SCYLLA",
+    "tight_loop", "BACKENDS", "CASSANDRA", "SCYLLA",
     "TIERS", "Clock", "RealClock", "RouteProfile", "RouteSchedule",
     "route_bdp_samples", "VirtualClock", "EventHandle", "EpochPlan",
     "FEED_KINDS", "Stack", "build_stack",

@@ -121,7 +121,7 @@ class Cluster:
     # -- load reporting -----------------------------------------------------
     def load_report(self) -> Dict[str, Dict[str, float]]:
         """Per-node served-load snapshot (replica-aware routing makes these
-        diverge under contention; the multi-host benchmark prints them).
+        diverge under contention; ``MultiHostRun``'s report carries them).
         ``egress_share`` is each node's fraction of total cluster egress —
         the imbalance signal the placement policies compete on."""
         now = self.clock.now()

@@ -20,8 +20,8 @@ codec in the configuration the paper measures, so their requests take the
 node ``serve()`` / ``SimConnection.request`` default path (``wire_bytes =
 payload bytes``, ``encode_seconds = 0``).  Our stack is allowed to enable
 codecs in the comparison — that asymmetry is part of the result, not a bug.
-``benchmarks/bench_competitors.py`` runs both against the adaptive stack on
-the same scenario cells.
+``tests/test_competitors.py`` holds them to the failure modes they model:
+both degrade with distance.
 """
 
 from __future__ import annotations

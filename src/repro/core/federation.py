@@ -45,7 +45,7 @@ Pieces, bottom up:
     no live node (cluster-level outage), or when every connection to it has
     failed mid-flight, the request *degrades* to the next cluster in
     failover order — possible because the keyspace is shared, exactly the
-    replica-cluster degradation the federation benchmark exercises.  A
+    replica-cluster degradation ``tests/test_federation.py`` exercises.  A
     once-guard keeps delivery exactly-once even when a hedge and a
     cross-cluster failover race.
 

@@ -5,8 +5,9 @@ at split-creation time, and a ``data`` table holding ``(uuid, label, blob)``
 rows fetched during training.  Features and annotations travel together in one
 row — the property that makes out-of-order batch assembly possible (Sec. 3.4).
 
-Blobs may be *lazy* (size-only) so benchmarks can model a 147 GB dataset
-without materializing it; integration tests and examples use real payloads.
+Blobs may be *lazy* (size-only) so the simulator can model a 147 GB dataset
+without materializing it; the device feeds, the examples and the chip
+benchmark use real payloads.
 """
 
 from __future__ import annotations
@@ -32,7 +33,7 @@ class DataRow:
     uuid: _uuid.UUID
     label: int
     size: int                       # payload size in bytes
-    payload: Optional[bytes] = None  # None => lazy blob (benchmarks)
+    payload: Optional[bytes] = None  # None => lazy blob (size-only rows)
 
     def materialize(self) -> bytes:
         """Full-size payload — always ``len() == self.size`` so arena copies

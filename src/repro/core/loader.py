@@ -2,7 +2,7 @@
 
 One object wires together: a cluster (or a handle to a shared one), the
 client connection pool, the epoch plan, and a prefetching strategy.  It is
-the single public entry point used by the data pipeline, the benchmarks and
+the single public entry point used by the data pipeline, ``build_stack`` and
 the examples.
 """
 
@@ -217,21 +217,4 @@ def tight_loop(loader: CassandraLoader, n_batches: int,
     }
 
 
-def consume_with_step_time(loader: CassandraLoader, n_batches: int,
-                           step_time: float, timeout: float = 600.0) -> dict:
-    """Training-consumer model: one fixed-cost step per batch (Sec. 4.2.2)."""
-    loader.start()
-    for _ in range(n_batches):
-        loader.next_batch(timeout=timeout)
-        loader.clock.sleep(step_time)
-    st = loader.stats
-    skip = max(0, min(2, n_batches - 2))   # short runs: never a negative slice
-    return {
-        "samples_per_s": st.samples_per_second(loader.cfg.batch_size,
-                                               skip=skip),
-        "batch_times": st.batch_times(skip=1),
-    }
-
-
-__all__ = ["LoaderConfig", "CassandraLoader", "tight_loop",
-           "consume_with_step_time"]
+__all__ = ["LoaderConfig", "CassandraLoader", "tight_loop"]

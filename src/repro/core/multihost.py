@@ -72,8 +72,8 @@ admission on the route-admission path, and per-tenant
 egress/hit-rate/stall/latency sections in the run report.  Tenant specs
 may carry their own sampling mode, so one run mixes a uniform
 latency-sensitive tenant with zipf batch tenants; ``host_sampling``
-expresses the same mixed workload without tenancy (the untenanted
-baseline of ``benchmarks/bench_tenancy.py``).  Scheduler state rides
+expresses the same mixed workload without tenancy, the untenanted
+baseline a tenanted run is compared with.  Scheduler state rides
 ``checkpoint()`` like flow snapshots do.
 
 Invariants this module maintains (property-tested in
@@ -215,8 +215,8 @@ class MultiHostConfig:
     tenants: Optional[Tuple[TenantSpec, ...]] = None
     tenant_of_host: Optional[Tuple[str, ...]] = None
     # Per-host sampling override ("uniform"/"zipf" per host), independent of
-    # tenancy — how the untenanted baseline of bench_tenancy expresses the
-    # same mixed workload.  Takes precedence over tenant-spec sampling;
+    # tenancy — the untenanted baseline of a tenanted run, the same mixed
+    # workload without a scheduler.  Takes precedence over tenant-spec sampling;
     # ``sampling="zipf"`` above still forces every host to zipf.
     host_sampling: Optional[Tuple[str, ...]] = None
     # Wire codec — LoaderConfig.wire_codec, one level up.  A codec name
@@ -725,8 +725,8 @@ class MultiHostRun:
 
         ``step_time`` models the per-step GPU compute all hosts perform in
         parallel (one sleep per round, not per host).  ``on_batch(host_id,
-        batch)`` is invoked for every delivered batch (tests and benchmarks
-        use it to audit delivery instead of re-deriving from logs).  Returns
+        batch)`` is invoked for every delivered batch (tests use it to
+        audit delivery instead of re-deriving from logs).  Returns
         a report dict; cumulative over repeated calls on the same run.
         """
         if not self._started:

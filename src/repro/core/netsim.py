@@ -6,8 +6,9 @@ GC pauses, disk read amplification).  This container has neither a WAN nor a
 database cluster, so we model them explicitly with a discrete-event simulator
 that the *actual loader code* runs against: the loader is callback-driven
 (as the paper's C++ loader is), and the simulator fires those callbacks either
-in virtual time (fast, perfectly reproducible benchmarks) or in real time
-(threaded timers; used by the JAX-integration tests and examples).
+in virtual time (fast, perfectly reproducible behavioural tests) or in real
+time (threaded timers; used by the device feeds on the chip, the
+JAX-integration tests and the examples).
 
 Key modelled effects, each traceable to a paper observation:
   * per-connection AIMD (CUBIC-like) bandwidth processes with Poisson
@@ -118,8 +119,7 @@ class VirtualClock(Clock):
       ring drains empty the clock jumps straight to the overflow head's
       bucket instead of walking empty slots.
 
-    ``events_processed`` counts fired events — the events/sec floor the
-    1000-host scale benchmark asserts reads it.
+    ``events_processed`` counts fired events.
     """
 
     # 512 buckets x 2 ms = a 1.024 s horizon: covers every RTT tier and
@@ -255,7 +255,7 @@ class VirtualClock(Clock):
         return True
 
     def run_until(self, predicate: Callable[[], bool], timeout: float = 120.0) -> bool:
-        # timeout is in *virtual* seconds to keep benchmarks deterministic.
+        # timeout is in *virtual* seconds to keep simulated runs deterministic.
         deadline = self._t + timeout
         while not predicate():
             if self._t > deadline or not self.step():
@@ -533,8 +533,8 @@ class RouteProfile:
 
 # Paper: Oregon / N.California / Stockholm from an Oregon p4d.24xlarge
 # (public NIC 50 Gb/s = 6.25e9 B/s).  Per-stream ceilings and loss rates are
-# chosen so the simulator reproduces the paper's measured aggregates
-# (see benchmarks/bench_tightloop.py).
+# chosen so a tight loop over the simulator reproduces the paper's measured
+# aggregates (Fig. 7).
 TIERS = {
     "local": RouteProfile("local", rtt=0.00005, conn_capacity=2.0e9, loss_per_byte=0.0),
     "low": RouteProfile("low", rtt=0.0008, conn_capacity=1.0e9, loss_per_byte=1e-11),
@@ -704,7 +704,7 @@ class BackendModel:
         return float(self.base_service * rng.lognormal(0.0, self.service_sigma))
 
 
-# Calibrated so the tight-loop benchmark reproduces the paper's Fig. 7:
+# Calibrated so a simulated tight loop reproduces the paper's Fig. 7:
 # ScyllaDB ~4.0 GB/s vs Cassandra ~1.6 GB/s at the high-latency tier, with
 # Cassandra's disk I/O ~2.25x its network throughput (block-read strategy) and
 # its small-chunk access pattern extracting less of the striped NVMe bandwidth.
@@ -729,13 +729,13 @@ def route_bdp_samples(route: "RouteProfile | str", n_conns: int,
                       backend: "BackendModel" = None,
                       t: Optional[float] = None) -> float:
     """True route BDP in *samples*, from first principles (the analytic
-    yardstick the flow-control tests and benchmarks measure the controller
+    yardstick the flow-control tests measure the controller
     against — not the controller's own estimate): expected bottleneck rate
     (connections, client NIC, node disk) times the effective round trip
     (propagation + median service + one transfer).
 
     With ``t`` given, any route schedules are applied at that instant — the
-    schedule-aware *oracle* BDP that ``bench_scenarios`` compares the
+    schedule-aware *oracle* BDP that ``core/scenarios.py`` compares the
     adaptive controller against.  Callers should treat outage windows
     (``prof.down_at(t)``) separately: the BDP of a down link is moot."""
     prof = TIERS[route] if isinstance(route, str) else route

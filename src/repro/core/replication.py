@@ -71,7 +71,8 @@ from .stats import windowed_series
 
 @dataclass(frozen=True)
 class ReplicationConfig:
-    """Knobs of hot-key promotion (defaults sized for benchmark scale)."""
+    """Knobs of hot-key promotion (defaults sized for a key universe of tens
+    of thousands of rows, as the simulator's multi-host runs use)."""
 
     track_k: int = 128          # space-saving counters: memory is O(track_k)
     window: float = 2.0         # access-rate horizon, seconds

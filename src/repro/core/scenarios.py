@@ -6,8 +6,8 @@ hand-written test fixture into data.  This module goes one step further and
 makes the whole *experiment* data: a ``Scenario`` is a frozen, JSON-round-
 trippable description of one network condition — base route parameters, the
 schedules and outage windows laid over them, the consumer workload (tight
-loop or paced training steps) and the run length — and the benchmark matrix
-(``benchmarks/bench_scenarios.py``) is just ``SCENARIOS x MODES``.
+loop or paced training steps) and the run length — and ``run_cell`` consumes
+one scenario under one mode, so a matrix is just ``SCENARIOS x MODES``.
 
 Modes compare three ways of choosing the prefetch in-flight budget on the
 same scenario:
@@ -22,11 +22,11 @@ same scenario:
   yardstick the adaptive controller is judged against, and the bar no
   fixed depth clears on every scenario.
 
-The headline assertion of the matrix benchmark: adaptive holds
-``>= oracle/1.5`` throughput on *every* cell with zero per-scenario tuning,
-while every fixed depth falls below that bound on at least one dynamic
-scenario — under-buffered after a latency spike multiplies the BDP, or
-pointlessly deep when the route shrinks under it.
+The property the matrix exists to show (``tests/test_scenarios.py``):
+adaptive holds ``>= oracle/1.5`` virtual-clock throughput on *every* cell
+with zero per-scenario tuning, while a fixed depth cannot — under-buffered
+after a latency spike multiplies the BDP, or pointlessly deep when the route
+shrinks under it.
 """
 
 from __future__ import annotations

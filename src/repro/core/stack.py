@@ -1,9 +1,10 @@
 """One-call stack construction: config object -> running data stack.
 
-Nine PRs of growth left every bench, example, and test hand-wiring the same
-chain — ``Cluster``/``FederatedCluster`` -> ``ConnectionPool`` ->
-``CassandraLoader`` -> ``DeviceFeed``/``ImageFeed`` — each slightly
-differently.  :func:`build_stack` is the one blessed spelling:
+The chain ``Cluster``/``FederatedCluster`` -> ``ConnectionPool`` ->
+``CassandraLoader`` -> ``DeviceFeed``/``ImageFeed`` is built in one place,
+so every caller (``run_training``, the examples, the chip benchmark's
+drivers, the tests) gets the same defaults and the same validation.
+:func:`build_stack` is the one spelling:
 
     from repro.core import LoaderConfig, build_stack
 
@@ -27,9 +28,10 @@ The config object decides the shape of the stack:
 
 Everything is keyword-only and validated up front: unknown feed kinds,
 missing feed parameters, or feed requests that the config cannot serve
-(token feeds need ``materialize=True``; per-host feeds over a
-``MultiHostConfig`` are not built here) raise ``ValueError``/``TypeError``
-at construction, not deep inside the first ``next_batch``.
+(feeds need ``materialize=True``, the image feed ``use_arena=True`` too;
+per-host feeds over a ``MultiHostConfig`` are not built here) raise
+``ValueError``/``TypeError`` at construction, not deep inside the first
+``next_batch``.
 
 Old hand-wiring keeps working — this module only composes public
 constructors and adds no behaviour of its own.
@@ -98,6 +100,9 @@ def _build_feed(kind: str, loader: CassandraLoader, *,
     if image_shape is None or out_shape is None:
         raise ValueError("feed='image' needs image_shape=(h, w, c) and "
                          "out_shape=(out_h, out_w)")
+    if loader.arena is None:
+        raise ValueError("feed='image' uploads whole arena slabs — set "
+                         "use_arena=True on the LoaderConfig")
     h, w, c = image_shape
     out_h, out_w = out_shape
     return ImageFeed(loader, h, w, c, out_h, out_w, mean=mean, std=std,
@@ -139,7 +144,8 @@ def build_stack(*, store: KVStore, uuids: Sequence[_uuid.UUID],
     feed
         ``None`` (default), ``"device"`` (token batches; needs ``seq_len``
         and ``config.materialize=True``) or ``"image"`` (uint8 image rows;
-        needs ``image_shape``/``out_shape`` and ``materialize=True``).
+        needs ``image_shape``/``out_shape``, ``materialize=True`` and
+        ``use_arena=True``).
     feed_prefetch, step_stats, mean, std, feed_seed, interpret
         Passed through to the feed constructor (``interpret=True`` runs the
         image feed's Pallas kernel in the interpreter, for CPU callers).

@@ -66,8 +66,8 @@ class TenantSpec:
     at their share) and groups the per-tenant report sections.  ``weight``
     sets the proportional share of NIC bandwidth left after floors.
     ``sampling``/``zipf_s`` describe the tenant's access pattern — hosts
-    tagged with a ``zipf`` tenant run the PR-5 skewed sampler, which is how
-    the aggressive batch tenant of the isolation bench is expressed."""
+    tagged with a ``zipf`` tenant run the skewed sampler, which is how an
+    aggressive batch tenant next to a latency tenant is expressed."""
 
     name: str
     qos: str = "batch"

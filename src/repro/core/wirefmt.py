@@ -16,7 +16,7 @@ Codecs:
 
 * ``none``        — identity.  Zero cost, zero extra simulator events:
   byte accounting stays bit-identical to the pre-codec loader (asserted by
-  ``bench_wirefmt``).
+  ``tests/test_wirefmt.py``).
 * ``byteshuffle`` — lz4-style lossless filter: a byte transpose (stride
   swept per payload — 4 groups the high bytes of int32/float32 streams
   into long runs, 3 de-interleaves RGB uint8 frames into channel planes)
@@ -29,10 +29,10 @@ Codecs:
   ``|x - decode(encode(x))| <= amax_block / 127`` per element.  Non-float
   payloads (length not a multiple of 4) take the store-raw escape.
 
-For *lazy* rows (size-only benchmark datasets, no real bytes) each codec
-also provides a deterministic ``encoded_size`` model calibrated against its
-real encoder on synthetic image-like entropy, so virtual-clock benchmarks
-bill the same ratios the real path would.
+For *lazy* rows (size-only datasets, no real bytes) each codec also
+provides a deterministic ``encoded_size`` model calibrated against its real
+encoder on synthetic image-like entropy, so virtual-clock runs bill the same
+ratios the real path would.
 """
 
 from __future__ import annotations

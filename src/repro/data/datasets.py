@@ -2,7 +2,7 @@
 
 ``SyntheticImageDataset`` mirrors ImageNet-1k statistics (Table 1: 1.28 M
 images, mean 115 kB, lognormal-ish size spread) without materializing bytes —
-used by the network benchmarks.
+used by the simulator's network tests, where only transfer sizes matter.
 
 ``SyntheticTokenDataset`` produces *real* payloads: token-sequence records
 (features+label serialized together, as the paper requires for OOO assembly)

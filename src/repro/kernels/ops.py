@@ -2,7 +2,8 @@
 
 The kernels compile for the TPU.  ``interpret=True`` runs them through the
 Pallas interpreter instead; only a caller that wants that (the CPU tests,
-a CPU benchmark run) passes it — the wrappers never pick a mode on their own.
+CPU callers of ``build_stack(feed="image")``) passes it — the wrappers never
+pick a mode on their own.
 """
 
 from __future__ import annotations

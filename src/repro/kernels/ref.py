@@ -1,6 +1,6 @@
 """Pure-jnp oracles for every Pallas kernel (the ground truth in tests) —
-plus a pure-NumPy ``crop_mirror_normalize_np`` that doubles as the host-side
-baseline transform in ``data.pipeline.ImageFeed``."""
+plus a pure-NumPy ``crop_mirror_normalize_np``, the reference that
+``data.pipeline.ImageFeed``'s batches are checked against."""
 
 from __future__ import annotations
 
@@ -78,9 +78,7 @@ def crop_mirror_normalize_np(img: np.ndarray, oy, ox, mirror,
 
     Same clamping semantics as the kernel entry point (offsets clip to the
     valid window) and the same float32 arithmetic, ``(crop - mean) *
-    inv_std``, so the two agree bit for bit.  Also serves as ``ImageFeed``'s
-    materialize-path host transform — the four-pass CPU pipeline the fused
-    kernel replaces.
+    inv_std``, so the two agree bit for bit.
     """
     B, H, W, C = img.shape
     oy = np.clip(np.asarray(oy, dtype=np.int64), 0, H - out_h)

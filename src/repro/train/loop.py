@@ -15,9 +15,8 @@ from typing import Callable, Dict, Optional
 
 import jax
 
-from repro.core import CassandraLoader, KVStore, LoaderConfig, VirtualClock
+from repro.core import KVStore, LoaderConfig, VirtualClock, build_stack
 from repro.core.stats import span
-from repro.data.pipeline import DeviceFeed
 from repro.train.checkpoint import CheckpointManager
 from repro.train.optimizer import OptimizerConfig
 from repro.train.step import init_state, make_train_step
@@ -36,9 +35,9 @@ class TrainLoopConfig:
     seed: int = 0
     # Compute seconds charged to the timeline per step instead of the
     # measured wall time of the jitted step.  With a virtual-clock loader
-    # this pins the consumer side of the simulation (deterministic stall /
-    # goodput numbers — what bench_training's goodput sweep gates on);
-    # None (default) charges the measured step time.
+    # this pins the consumer side of the simulation, so stall and goodput
+    # numbers are deterministic for tests; None (default) charges the
+    # measured step time.
     charge_step_time: Optional[float] = None
 
 
@@ -75,12 +74,13 @@ def run_training(model, store: KVStore, uuids, loader_cfg: LoaderConfig,
         else:
             state = init_state(model, opt_cfg, jax.random.PRNGKey(loop_cfg.seed))
 
-    loader = CassandraLoader(store, uuids, loader_cfg)
+    stack = build_stack(store=store, uuids=uuids, config=loader_cfg,
+                        feed="device", seq_len=loop_cfg.seq_len)
+    loader, feed = stack.loader, stack.feed
     loader.start(epoch=loader_pos["epoch"], cursor=loader_pos["cursor"])
     # adaptive runs resume at the checkpointed operating point instead of
     # re-slow-starting from scratch (no-op in static mode / old checkpoints)
     loader.restore_flow(loader_pos.get("flow"))
-    feed = DeviceFeed(loader, loop_cfg.seq_len)
     ss = feed.step_stats
     clk = loader.clock
     virtual = isinstance(clk, VirtualClock)
@@ -139,7 +139,7 @@ def run_training(model, store: KVStore, uuids, loader_cfg: LoaderConfig,
     if ckpt:
         ckpt.save(loop_cfg.total_steps, state, extra=ckpt_extra(),
                   blocking=True)
-    loader.close()
+    stack.close()
     return {"state": state, "history": history,
             "stats": ss.summary(B, skip=1), "step_stats": ss}
 

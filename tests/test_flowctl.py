@@ -182,8 +182,8 @@ def test_budget_converges_to_route_bdp(rtt_ms, conn_mbps):
 
 
 # ---------------------------------------------------------------------------
-# The headline invariants (small-scale twin of benchmarks/bench_ramp.py's
-# flowctl section, which asserts the same from results/flowctl_ramp.json)
+# The headline invariants of adaptive flow control: it matches the best
+# static depth on the WAN route and does not over-buffer a local one
 # ---------------------------------------------------------------------------
 
 def _tput(store, uuids, route, mode, k, n_batches=70, B=256):
@@ -327,7 +327,7 @@ def test_cross_shape_restore_plain_to_federated(store_uuids):
 def test_retry_counters_are_per_window(store_uuids):
     """failovers / cluster_failovers report the run() window's delta, so a
     recovered outage stops showing up in later windows (matches the
-    window-delta egress accounting and docs/BENCHMARKS.md)."""
+    window-delta egress accounting)."""
     from repro.core import ClusterSpec
     store, uuids = store_uuids
     cfg = MultiHostConfig(

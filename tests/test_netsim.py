@@ -113,7 +113,7 @@ def test_multihost_sim_determinism():
 
 
 def test_deterministic_replay():
-    """Same seed => byte-identical event trace (required for benchmarks)."""
+    """Same seed => byte-identical event trace (virtual-clock runs reproduce)."""
 
     def run():
         clk = VirtualClock()

@@ -23,7 +23,7 @@ from repro.core import (ClusterSpec, KVStore, MultiHostConfig, MultiHostRun,
                         ReplicationConfig, ZipfPlan)
 from repro.core.federation import FederatedCluster, FederatedRing
 from repro.core.kvstore import DataRow, MetaRow, make_uuid
-from repro.core.netsim import VirtualClock
+from repro.core.netsim import RouteProfile, RouteSchedule, VirtualClock
 from repro.core.replication import HotKeyTracker, ReplicaCache
 from repro.data.datasets import SyntheticImageDataset, ingest
 
@@ -431,3 +431,79 @@ def test_rebalanced_ownership_rides_checkpoint(store_uuids):
     assert ck["federation"] == b.federation.ring.metadata()
     rep = b.run(2)
     assert rep["ownership_weights"] == weights
+
+
+# ---------------------------------------------------------------------------
+# Everything moving at once: a WAN member whose latency creeps x6 while a
+# Zipf hotset rotates, with replication, demotion and cadenced rebalancing
+# ---------------------------------------------------------------------------
+
+TRACKING_ROUNDS = 30
+
+
+@pytest.fixture(scope="module")
+def tracking(store_uuids):
+    store, uuids = store_uuids
+    far = RouteProfile(
+        "wan/creep", rtt=0.08, conn_capacity=0.5e9, loss_per_byte=1e-11,
+        schedules=(RouteSchedule("latency", "ramp", factor=6.0,
+                                 at=1.0, until=5.0),))
+    cfg = MultiHostConfig(
+        n_hosts=2, batch_size=128, prefetch_buffers=8, io_threads=4,
+        ramp_every=1, hedge_after="auto", seed=23,
+        placement="replication_aware",
+        clusters=(ClusterSpec("near", route="low", n_nodes=4,
+                              replication_factor=2, weight=1),
+                  ClusterSpec("far", route=far, n_nodes=4,
+                              replication_factor=2, weight=2)),
+        flow_control="adaptive", route_admission=True,
+        sampling="zipf", zipf_s=1.3, zipf_shift_every=2,
+        rebalance_every=5,
+        replication=ReplicationConfig(window=1.0, demote_after=0.5,
+                                      min_count=6))
+    # a small key universe cycles Zipf epochs, and so hotset rotations,
+    # fast enough that the run sees several
+    run = MultiHostRun(store, uuids[:2400], cfg).start()
+    return cfg, run.run(TRACKING_ROUNDS, step_time=0.05)
+
+
+def test_tracking_hotset_shift_demotes_replicas(tracking):
+    _, rep = tracking
+    assert rep["replication"]["demotions"] >= 1
+
+
+def test_tracking_rebalance_fires_on_cadence(tracking):
+    cfg, rep = tracking
+    assert rep["rebalances"] == TRACKING_ROUNDS // cfg.rebalance_every
+
+
+def test_tracking_replica_cache_serves_hits(tracking):
+    _, rep = tracking
+    assert rep["replica_hit_frac"] > 0.0
+
+
+def test_rebalance_shifts_weight_toward_spare_member(store_uuids):
+    """The keyspace is declared WAN-heavy (overseas weight 3); the local
+    member's controllers measure spare BDP, so a rebalance moves serving
+    weight toward it, and the WAN then carries a smaller share of bytes."""
+    store, uuids = store_uuids
+    specs = (ClusterSpec("onprem", route="local", n_nodes=6,
+                         replication_factor=2, weight=1,
+                         node_egress_bandwidth=1.25e9),
+             ClusterSpec("overseas", route="high", n_nodes=4,
+                         replication_factor=2, weight=3,
+                         node_egress_bandwidth=1.25e9))
+    run = MultiHostRun(store, uuids, MultiHostConfig(
+        n_hosts=4, batch_size=256, prefetch_buffers=24, io_threads=8,
+        ramp_every=1, hedge_after=1.0, seed=19, placement="cluster_aware",
+        clusters=specs, flow_control="adaptive")).start()
+    before = run.run(8)
+    w0 = before["ownership_weights"]
+    w1 = run.rebalance(step=0.3)
+    after = run.run(8)
+
+    def local_share(w):
+        return w["onprem"] / sum(w.values())
+
+    assert local_share(w1) > local_share(w0)
+    assert after["wan_bytes_share"] < before["wan_bytes_share"]

@@ -3,7 +3,7 @@
 Covers the schedule sampler (``netsim.RouteSchedule``/``RouteProfile``),
 the flow controller's re-convergence machinery (min-RTT anchor, dead-band
 ratchet, regime shifts, load-aware backoff), replica demotion consistency,
-and the ``core/scenarios.py`` declarative layer the benchmark matrix runs.
+and the ``core/scenarios.py`` declarative layer and its scenario matrix.
 """
 
 import json
@@ -14,8 +14,8 @@ import pytest
 
 from repro.core import (CassandraLoader, Cluster, ConnectionPool,
                         FlowControlConfig, FlowController, KVStore,
-                        LoaderConfig, OracleDepthController, Scenario,
-                        SCENARIOS, matrix, run_cell)
+                        LoaderConfig, OracleDepthController, QUICK_MATRIX,
+                        Scenario, SCENARIOS, matrix, run_cell)
 from repro.core.netsim import TIERS, RouteProfile, RouteSchedule, VirtualClock
 from repro.core.replication import ReplicaCache
 from repro.data.datasets import SyntheticImageDataset, ingest
@@ -349,3 +349,18 @@ def test_run_cell_modes_smoke(store_uuids):
     assert "steady_depth" in out["adaptive"]
     with pytest.raises(ValueError, match="unknown mode"):
         run_cell(store, uuids[:2000], sc, "psychic")
+
+
+# The oracle knows every schedule in advance; the adaptive controller only
+# measures.  Holding >= 1/1.5 of the oracle's virtual-clock throughput on
+# every cell, with no per-scenario tuning, is what re-convergence buys.
+ORACLE_SLACK = 1.5
+
+
+@pytest.mark.parametrize("name", QUICK_MATRIX)
+def test_adaptive_within_oracle_slack_on_every_quick_cell(store_uuids, name):
+    store, uuids = store_uuids
+    sc = SCENARIOS[name]
+    adaptive = run_cell(store, uuids, sc, "adaptive")["MBps"]
+    oracle = run_cell(store, uuids, sc, "oracle")["MBps"]
+    assert adaptive >= oracle / ORACLE_SLACK

@@ -125,6 +125,85 @@ def test_image_feed_needs_shapes(small_store):
                     config=_cfg(materialize=True), feed="image")
 
 
+def test_image_feed_needs_arena(small_store):
+    store, uuids = small_store
+    with pytest.raises(ValueError, match="use_arena"):
+        build_stack(store=store, uuids=uuids,
+                    config=_cfg(materialize=True), feed="image",
+                    image_shape=(32, 32, 3), out_shape=(24, 24))
+
+
+def test_run_training_rejects_unmaterialized_config_at_construction():
+    """``run_training`` builds its feed through ``build_stack``, so a config
+    the token feed cannot serve fails there, before any batch is pulled."""
+    from repro.configs.base import ArchConfig
+    from repro.data.datasets import SyntheticTokenDataset
+    from repro.models import build_model
+    from repro.train.loop import TrainLoopConfig, run_training
+
+    store = KVStore()
+    uuids = ingest(store, SyntheticTokenDataset(n_samples=64, seq_len=8,
+                                                vocab=64, seed=1))
+    model = build_model(ArchConfig(
+        name="stack-test-lm", family="dense", n_layers=1, d_model=16,
+        n_heads=2, n_kv_heads=1, d_ff=32, vocab=64, head_dim=8,
+        dtype="float32", remat=False))
+    with pytest.raises(ValueError, match="consumes real payload bytes"):
+        run_training(model, store, uuids,
+                     LoaderConfig(batch_size=8, route="low", seed=2),
+                     TrainLoopConfig(total_steps=1, seq_len=8))
+
+
+def _token_feed_stack():
+    from repro.data.datasets import SyntheticTokenDataset
+    store = KVStore()
+    uuids = ingest(store, SyntheticTokenDataset(n_samples=96, seq_len=8,
+                                                vocab=64, seed=4))
+    return build_stack(store=store, uuids=uuids,
+                       config=LoaderConfig(batch_size=8, route="low",
+                                           materialize=True,
+                                           out_of_order=False, seed=5),
+                       feed="device", seq_len=8)
+
+
+def _image_feed_stack():
+    from repro.data.datasets import SyntheticPixelDataset
+    ds = SyntheticPixelDataset(n_samples=96, h=32, w=32, c=3, seed=2)
+    store = KVStore()
+    uuids = ingest(store, ds)
+    return build_stack(store=store, uuids=uuids,
+                       config=LoaderConfig(batch_size=8, route="low",
+                                           materialize=True,
+                                           out_of_order=False,
+                                           use_arena=True,
+                                           arena_slot_bytes=ds.nbytes,
+                                           seed=5),
+                       feed="image", image_shape=(ds.h, ds.w, ds.c),
+                       out_shape=(24, 24), interpret=True)
+
+
+@pytest.mark.parametrize("make", [_token_feed_stack, _image_feed_stack],
+                         ids=["device", "image"])
+def test_both_feeds_deliver_through_the_shared_pull_loop(make):
+    """Both feeds keep ``prefetch`` formed batches queued past what the
+    consumer saw, and ``state()`` rewinds the loader by exactly that queue,
+    so a checkpoint of either feed resumes at the consumer's position."""
+    from repro.data.pipeline import _Feed
+    stack = make()
+    feed = stack.feed
+    assert type(feed)._pull_one is _Feed._pull_one
+    assert type(feed).__next__ is _Feed.__next__
+    for _ in range(3):
+        batch, meta = next(feed)
+        assert len(meta.samples) == 8
+    assert len(feed._queue) == feed.prefetch == 2
+    assert stack.loader.state()["consumed"] == 3 + 2
+    pos = feed.state()
+    assert pos["consumed"] == 3 and pos["cursor"] == 3 * 8
+    assert len(feed.step_stats.wait_s) == 3
+    stack.close()
+
+
 def test_multihost_rejects_feed_and_external_pieces(small_store):
     store, uuids = small_store
     cfg = MultiHostConfig(n_hosts=2, batch_size=64, route="low", n_nodes=2)

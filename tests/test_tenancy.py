@@ -499,3 +499,26 @@ def test_device_feed_restore_exactly_once_tenanted():
     want = [str(u) for u in restore.loaders[0].plan.permutation(0)]
     assert len(seen) == len(set(seen))           # no duplicates
     assert sorted(seen) == sorted(want)          # nothing skipped
+
+
+def test_capped_batch_tenant_still_served(store_uuids):
+    """A zipf batch tenant capped below its demand beside a latency tenant,
+    with tenant admission deferring its over-share requests, is throttled,
+    not starved: its hosts keep delivering."""
+    store, uuids = store_uuids
+    specs = (TenantSpec("serve", qos="latency", weight=3.0,
+                        rate_ceiling=0.8e9),
+             TenantSpec("train", qos="batch", weight=1.0,
+                        rate_ceiling=2.6e9, sampling="zipf", zipf_s=1.3))
+    cfg = _mh_cfg(4, tenants=specs,
+                  tenant_of_host=("serve", "train", "train", "train"),
+                  route_admission=True, batch_size=256, io_threads=8,
+                  prefetch_buffers=8, route="high", seed=11,
+                  node_egress_bandwidth=1.25e9,
+                  client_ingress_bandwidth=5.0e9)
+    run = MultiHostRun(store, uuids[:6000], cfg).start()
+    rep = run.run(6)
+    train = rep["tenants"]["train"]
+    assert train["admit_denials"] > 0            # admission did defer it
+    assert train["egress_Bps"] > 0.0
+    assert all(b > 0 for b in rep["per_client_Bps"][1:])

@@ -307,3 +307,20 @@ def test_io_scaling_throughput_not_much_worse(store_uuids):
         return tight_loop(ld, 25)["throughput_Bps"]
 
     assert run(True) > 0.7 * run(False)
+
+
+def test_codec_deepens_adaptive_budget_on_wan_route(store_uuids):
+    """On the 150 ms route the wire is the bottleneck: byteshuffle moves
+    more samples per wire byte, the controller measures the higher sample
+    rate, and its budget converges at least 1.1x deeper than without it."""
+    store, uuids = store_uuids
+
+    def budget(codec):
+        cfg = LoaderConfig(batch_size=256, route="high", wire_codec=codec,
+                           flow_control="adaptive", seed=13, n_nodes=4,
+                           replication_factor=2)
+        ld = CassandraLoader(store, uuids, cfg)
+        tight_loop(ld, 150)
+        return ld.flow_controller.budget()
+
+    assert budget("byteshuffle") >= 1.1 * budget("none")
